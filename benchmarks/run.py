@@ -28,6 +28,9 @@ def main() -> None:
         "readwrite", "serving", "macro", "tail", "incr", "durability",
         "kernels"}
 
+    from repro.compile_cache import configure_compile_cache
+    configure_compile_cache()
+
     from benchmarks.common import emit_header
     emit_header()
 

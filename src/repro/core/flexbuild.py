@@ -173,6 +173,12 @@ def flexbuild(store=None, components: Optional[Sequence[str]] = None, *,
     if session_kwargs and not serve:
         raise TypeError(f"unexpected arguments {sorted(session_kwargs)} "
                         f"(session knobs need serve=True)")
+    if serve and mesh is not None:
+        # the session's QueryService takes no mesh yet: refuse rather
+        # than serve single-device while the caller asked for sharding
+        raise TypeError("flexbuild(serve=True) cannot shard over a mesh "
+                        "yet; build the loose Deployment (serve=False) "
+                        "for sharded GRAPE analytics")
 
     # interfaces pull in their engines implicitly
     engines_wanted = {c for c in comps if c in ENGINE_COMPONENTS}

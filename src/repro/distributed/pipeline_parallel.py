@@ -17,7 +17,6 @@ from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -68,11 +67,11 @@ def gpipe_loss(stage_fn: Callable, loss_fn: Callable,
         return total / jnp.maximum(count, 1.0)
 
     pspec_params = jax.tree_util.tree_map(lambda _: P(axis), stage_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         spmd, mesh=mesh,
         in_specs=(pspec_params, P(), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stage_params, x_micro, y_micro)
 

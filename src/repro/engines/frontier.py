@@ -455,7 +455,6 @@ class FragmentFrontierExecutor:
         in place hits the same compiled program (DESIGN.md §15)."""
         n = self.pg.n_vertices
         if self.mesh is not None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             a_src, a_row, a_w = hop_args
@@ -467,15 +466,15 @@ class FragmentFrontierExecutor:
                 owned = self._owned_edges(src[0], row[0], w[0], xr)
                 buf = jax.lax.dynamic_update_slice(
                     jnp.zeros((B, npad), jnp.float32), owned, (0, start[0]))
-                # disjoint owned ranges: psum is the fragment exchange
-                return jax.lax.psum(buf, "data")[None]
+                # disjoint owned ranges: psum is the fragment exchange,
+                # and its result is identical on every chip
+                return jax.lax.psum(buf, "data")
 
-            fn = shard_map(frag_fn, mesh=self.mesh,
-                           in_specs=(P("data"), P("data"), P("data"),
-                                     P("data"), P()),
-                           out_specs=P("data"))
-            out = fn(a_src, a_row, a_w, starts, x)
-            return out[0][:, :n]
+            fn = jax.shard_map(frag_fn, mesh=self.mesh,
+                               in_specs=(P("data"), P("data"), P("data"),
+                                         P("data"), P()),
+                               out_specs=P())
+            return fn(a_src, a_row, a_w, starts, x)[:, :n]
 
         if self.use_kernels:
             owned = [self._owned_slab(hop_args[f], x)
@@ -508,7 +507,6 @@ class FragmentFrontierExecutor:
         owned ranges (DESIGN.md §13)."""
         n = self.pg.n_vertices
         if self.mesh is not None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             a_src, a_row, a_w = hop_args
@@ -522,14 +520,13 @@ class FragmentFrontierExecutor:
                     jnp.full((B, npad), jnp.inf, jnp.float32), owned,
                     (0, start[0]))
                 # disjoint owned ranges filled with +inf: pmin exchanges
-                return jax.lax.pmin(buf, "data")[None]
+                return jax.lax.pmin(buf, "data")
 
-            fn = shard_map(frag_fn, mesh=self.mesh,
-                           in_specs=(P("data"), P("data"), P("data"),
-                                     P("data"), P()),
-                           out_specs=P("data"))
-            out = fn(a_src, a_row, a_w, starts, d)
-            return out[0][:, :n]
+            fn = jax.shard_map(frag_fn, mesh=self.mesh,
+                               in_specs=(P("data"), P("data"), P("data"),
+                                         P("data"), P()),
+                               out_specs=P())
+            return fn(a_src, a_row, a_w, starts, d)[:, :n]
 
         if self.use_kernels:
             owned = [self._owned_slab_minplus(hop_args[f], d)
@@ -669,7 +666,7 @@ class FragmentFrontierExecutor:
                 "vertex ids exceed float32 exact-integer range")
         prefix = self._prefix_fn(program)
         head = program.head
-        iota = jnp.arange(self.pg.n_vertices, dtype=jnp.float32)
+        n = self.pg.n_vertices
         agg_fns = {a.name: a.fn for a in tail.aggs}
 
         def dev(e, ctx, base):
@@ -681,7 +678,8 @@ class FragmentFrontierExecutor:
                 if e.prop is not None:
                     return ctx["props"][e.prop], zero
                 if e.alias == head:
-                    return iota, zero
+                    # traced iota, not a captured [N] constant
+                    return jnp.arange(n, dtype=jnp.float32), zero
                 return ctx["aggs"][e.alias], zero
             if isinstance(e, Const):
                 return jnp.float32(float(e.value)), zero

@@ -55,7 +55,7 @@ def pagerank(engine: GrapeEngine, damping: float = 0.85,
     if warm_start is not None:
         init_state = {"rank": _pad_state(warm_start, n, 0.0)}
     return run_pregel(engine, prog, max_steps,
-                      cache_key=("pagerank", damping),
+                      cache_key=("pagerank", damping, max_steps, tol),
                       init_state=init_state)["rank"]
 
 
@@ -87,7 +87,7 @@ def bfs(engine: GrapeEngine, source: int, max_steps: int = 64,
         d = _pad_state(warm_start, n, jnp.inf).at[source].set(0.0)
         init_state = {"depth": d}
     return run_pregel(engine, prog, max_steps,
-                      cache_key=("bfs", source),
+                      cache_key=("bfs", source, max_steps),
                       init_state=init_state)["depth"]
 
 
@@ -121,7 +121,7 @@ def sssp(engine: GrapeEngine, source: int, max_steps: int = 128,
         d = _pad_state(warm_start, n, jnp.inf).at[source].set(0.0)
         init_state = {"dist": d}
     return run_pregel(engine, prog, max_steps,
-                      cache_key=("sssp", source),
+                      cache_key=("sssp", source, max_steps),
                       init_state=init_state)["dist"]
 
 
@@ -145,7 +145,7 @@ def wcc(engine: GrapeEngine, max_steps: int = 64,
     if warm_start is not None:
         init_state = {"lab": _pad_state(warm_start,
                                         engine.frags.n_vertices, "iota")}
-    return run_pregel(engine, prog, max_steps, cache_key=("wcc",),
+    return run_pregel(engine, prog, max_steps, cache_key=("wcc", max_steps),
                       init_state=init_state)["lab"].astype(jnp.int32)
 
 

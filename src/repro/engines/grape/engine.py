@@ -9,8 +9,9 @@ Fragment execution follows the paper's design translated to JAX:
   BEFORE a single ``psum``/``pmin``/``pmax`` exchange — the literal analogue
   of GRAPE's "aggregate fragmented small messages into a continuous compact
   buffer before dispatching" (the paper trades latency for throughput);
-- the scatter-add hot loop is the Pallas SpMV kernel's job on TPU
-  (``repro.kernels``); the jnp fallback is used on CPU.
+- the scatter-add hot loop is a jnp scatter on every backend; the Pallas
+  segment-sum behind ``use_kernels`` runs only in tests (the v5e
+  compiler refuses it as written).
 """
 
 from __future__ import annotations
@@ -35,9 +36,15 @@ COMBINERS = {
 }
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["indices", "e_src", "e_mask", "weights",
+                                "owned_start", "out_degree"],
+                   meta_fields=["n_vertices", "v_per_frag"])
 @dataclasses.dataclass
 class FragmentArrays:
-    """Device-resident stacked fragment arrays."""
+    """Device-resident stacked fragment arrays. A pytree: jitted fixpoints
+    take it as an argument, so the edge arrays never bake into the
+    compiled program as constants."""
 
     indices: jnp.ndarray        # [F, E] global neighbor ids; PAD_SENTINEL
     #                             entries are rebased to 0 with e_mask False
@@ -52,7 +59,7 @@ class FragmentArrays:
     v_per_frag: int
 
 
-def _prepare(frags: Fragments) -> FragmentArrays:
+def _prepare(frags: Fragments, mesh=None) -> FragmentArrays:
     F, E = frags.indices.shape
     e_src = np.zeros((F, E), np.int32)
     for f in range(F):
@@ -61,13 +68,25 @@ def _prepare(frags: Fragments) -> FragmentArrays:
             np.searchsorted(ptr, np.arange(E), side="right") - 1,
             0, frags.v_per_frag - 1)
     mask = frags.indices != PAD_SENTINEL
+    # under a mesh each chip holds its own fragment's edges; the [N]
+    # degree vector is replicated
+    if mesh is None:
+        stacked = replicated = jnp.asarray
+    else:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        def stacked(a):
+            return jax.device_put(a, NamedSharding(mesh, P("data")))
+
+        def replicated(a):
+            return jax.device_put(a, NamedSharding(mesh, P()))
     return FragmentArrays(
-        indices=jnp.asarray(np.where(mask, frags.indices, 0)),
-        e_src=jnp.asarray(e_src),
-        e_mask=jnp.asarray(mask),
-        weights=None if frags.weights is None else jnp.asarray(frags.weights),
-        owned_start=jnp.asarray(frags.owned_start),
-        out_degree=jnp.asarray(frags.out_degree),
+        indices=stacked(np.where(mask, frags.indices, 0)),
+        e_src=stacked(e_src),
+        e_mask=stacked(mask),
+        weights=None if frags.weights is None else stacked(frags.weights),
+        owned_start=stacked(frags.owned_start),
+        out_degree=replicated(frags.out_degree),
         n_vertices=frags.n_vertices,
         v_per_frag=frags.v_per_frag,
     )
@@ -84,8 +103,10 @@ class GrapeEngine:
             n_frags = int(np.prod([mesh.shape[a] for a in mesh.axis_names
                                    if a == "data"])) or n_frags
         self.n_frags = n_frags
-        self.frags = _prepare(partition(store, n_frags, reorder=reorder))
+        self.frags = _prepare(partition(store, n_frags, reorder=reorder),
+                              mesh)
         self.use_kernels = use_kernels
+        self._sharded: Dict[Tuple[str, bool], Callable] = {}
 
     # ------------------------------------------------------------ superstep
     def _scatter(self, fa: FragmentArrays, owned_vals: jnp.ndarray,
@@ -112,33 +133,48 @@ class GrapeEngine:
         buf = init((fa.n_vertices,), vals.dtype)
         return scat(buf, fa.indices, vals)
 
+    def _sharded_superstep(self, combiner: str, use_weights: bool):
+        """The jitted shard_map superstep over the mesh's ``data`` axis,
+        one per (combiner, use_weights). Jitted so eager callers (FLASH,
+        PIE) run it as one program; inside a jitted fixpoint it inlines."""
+        key = (combiner, use_weights)
+        fn = self._sharded.get(key)
+        if fn is not None:
+            return fn
+        from jax.sharding import PartitionSpec as P
+
+        coll = COMBINERS[combiner][2]
+        meta = self.frags
+
+        def frag_fn(idx, esrc, emask, w, vals):
+            local_fa = dataclasses.replace(
+                meta, indices=idx[0], e_src=esrc[0], e_mask=emask[0],
+                weights=None if w is None else w[0])
+            contrib = self._scatter(local_fa, vals[0], combiner,
+                                    use_weights)
+            # the collective's result is identical on every chip
+            return getattr(jax.lax, coll)(contrib, "data")
+
+        w_spec = None if meta.weights is None else P("data")
+        fn = jax.jit(jax.shard_map(
+            frag_fn, mesh=self.mesh,
+            in_specs=(P("data"), P("data"), P("data"), w_spec, P("data")),
+            out_specs=P()))
+        self._sharded[key] = fn
+        return fn
+
     def superstep(self, owned_vals: jnp.ndarray, combiner: str = "sum",
-                  use_weights: bool = False) -> jnp.ndarray:
-        """owned_vals [F, v_per] → combined messages [N] (replicated)."""
-        fa = self.frags
+                  use_weights: bool = False,
+                  frags: Optional[FragmentArrays] = None) -> jnp.ndarray:
+        """owned_vals [F, v_per] → combined messages [N] (replicated).
+        ``frags`` defaults to the engine's arrays; a jitted caller passes
+        its traced copy."""
+        fa = self.frags if frags is None else frags
 
         if self.mesh is not None:
-            from jax.experimental.shard_map import shard_map
-            from jax.sharding import PartitionSpec as P
-
-            coll = COMBINERS[combiner][2]
-
-            def frag_fn(idx, esrc, emask, w, vals):
-                local_fa = dataclasses.replace(
-                    fa, indices=idx[0], e_src=esrc[0], e_mask=emask[0],
-                    weights=None if w is None else w[0])
-                contrib = self._scatter(local_fa, vals[0], combiner,
-                                        use_weights)
-                out = getattr(jax.lax, coll)(contrib, "data")
-                return out[None]
-
-            w = fa.weights
-            in_specs = (P("data"), P("data"), P("data"),
-                        None if w is None else P("data"), P("data"))
-            fn = shard_map(frag_fn, mesh=self.mesh,
-                           in_specs=in_specs, out_specs=P("data"))
-            msgs = fn(fa.indices, fa.e_src, fa.e_mask, w, owned_vals)
-            return msgs[0]
+            fn = self._sharded_superstep(combiner, use_weights)
+            return fn(fa.indices, fa.e_src, fa.e_mask, fa.weights,
+                      owned_vals)
 
         contribs = jax.vmap(
             lambda i, s, m, w, v: self._scatter(

@@ -47,17 +47,19 @@ def run_pregel(engine: GrapeEngine, prog: VertexProgram, max_steps: int,
     n = engine.frags.n_vertices
     state = prog.init(n) if init_state is None else \
         {k: jnp.asarray(v) for k, v in init_state.items()}
-    deg = engine.out_degree.astype(jnp.float32)
 
-    def one_step(state, step):
+    def one_step(state, step, frags):
+        deg = frags.out_degree.astype(jnp.float32)
         emitted = prog.send(state, deg)                 # [N]
         owned = engine.owned_view(emitted)              # [F, v_per]
-        msgs = engine.superstep(owned, prog.combiner, prog.use_weights)
+        msgs = engine.superstep(owned, prog.combiner, prog.use_weights,
+                                frags)
         return prog.update(state, msgs, step)
 
     if not jit:
         for step in range(max_steps):
-            new_state = one_step(state, jnp.asarray(step, jnp.int32))
+            new_state = one_step(state, jnp.asarray(step, jnp.int32),
+                                 engine.frags)
             if prog.residual_key is not None:
                 res = float(jnp.sum(jnp.abs(
                     new_state[prog.residual_key] - state[prog.residual_key])))
@@ -70,15 +72,17 @@ def run_pregel(engine: GrapeEngine, prog: VertexProgram, max_steps: int,
 
     # jitted fixpoint: the whole superstep loop is ONE device program
     # (lax.while_loop with the residual convergence check on device) —
-    # GRAPE's tight loop, no per-superstep host dispatch.
-    def fixpoint(state):
+    # GRAPE's tight loop, no per-superstep host dispatch. The fragment
+    # arrays are an argument, not closed over: constants of the edge
+    # list's size would bake into the program and stall its compile.
+    def fixpoint(state, frags):
         def cond(carry):
             _, step, res = carry
             return (step < max_steps) & (res > prog.tol)
 
         def body(carry):
             st, step, _ = carry
-            new = one_step(st, step)
+            new = one_step(st, step, frags)
             if prog.residual_key is not None:
                 diff = jnp.abs(new[prog.residual_key]
                                - st[prog.residual_key])
@@ -103,4 +107,4 @@ def run_pregel(engine: GrapeEngine, prog: VertexProgram, max_steps: int,
             cache[cache_key] = fx
     else:
         fx = jax.jit(fixpoint)
-    return fx(state)
+    return fx(state, engine.frags)

@@ -297,7 +297,6 @@ class FragmentSampleExecutor:
                u: jnp.ndarray) -> jnp.ndarray:
         """ids [M] global (< 0 ⇒ PAD), u [M, K] → sampled neighbors [M, K]."""
         if self.mesh is not None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             def frag_fn(ell, deg, start, ids, u):
@@ -305,13 +304,13 @@ class FragmentSampleExecutor:
                 # (use_kernels is forced off under a mesh, so _frag_draws
                 # runs the jnp form here)
                 contrib = self._frag_draws(ell[0], deg[0], start[0], ids, u)
-                return jax.lax.psum(contrib, "data")[None]
+                return jax.lax.psum(contrib, "data")
 
-            fn = shard_map(frag_fn, mesh=self.mesh,
-                           in_specs=(P("data"), P("data"), P("data"),
-                                     P(), P()),
-                           out_specs=P("data"))
-            return fn(t["ell"], t["deg"], t["starts"], ids, u)[0] - 1
+            fn = jax.shard_map(frag_fn, mesh=self.mesh,
+                               in_specs=(P("data"), P("data"), P("data"),
+                                         P(), P()),
+                               out_specs=P())
+            return fn(t["ell"], t["deg"], t["starts"], ids, u) - 1
 
         if self.exchange == "psum":
             acc = self._frag_draws(t["ell"][0], t["deg"][0], 0, ids, u)
@@ -345,19 +344,18 @@ class FragmentSampleExecutor:
         labels): psum of disjoint owned slices; PAD ids get zero rows. On
         the stacked path the same contract is one padded-row take."""
         if self.mesh is not None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             def frag_fn(table, start, ids):
                 rows = self._frag_gather(table[0], start[0], ids)
-                return jax.lax.psum(rows, "data")[None]
+                return jax.lax.psum(rows, "data")
 
-            fn = shard_map(frag_fn, mesh=self.mesh,
-                           in_specs=(P("data"), P("data"), P()),
-                           out_specs=P("data"))
+            fn = jax.shard_map(frag_fn, mesh=self.mesh,
+                               in_specs=(P("data"), P("data"), P()),
+                               out_specs=P())
             # starts is pure fragment-offset config (arange(F)·v_per) —
             # identical for every advance() generation, safe as a constant
-            return fn(table_stacked, self.starts, ids)[0]
+            return fn(table_stacked, self.starts, ids)
 
         if self.exchange == "psum":
             acc = self._frag_gather(table_stacked[0], 0, ids)

@@ -157,7 +157,9 @@ def segment_sum(vals: jnp.ndarray, segs: jnp.ndarray, n_out: int, *,
     if pad:
         vals = jnp.concatenate([vals, jnp.zeros((pad,), vals.dtype)])
         segs = jnp.concatenate([segs, jnp.full((pad,), -1, segs.dtype)])
-    n_pad = -(-max(n_out, window) // window) * window
+    # one spare window: a tile's window opens at its first segment rounded
+    # down to 128, so it may reach up to ``window`` rows past n_out
+    n_pad = (-(-n_out // window) + 1) * window
     # precondition check is host-side metadata in the engine; here assume
     # sorted inputs (CSC order) — violations are the caller's fallback.
     out = ss.segment_sum_sorted(vals, segs.astype(jnp.int32), n_pad,

@@ -27,7 +27,9 @@ def _segsum_kernel(vals_ref, segs_ref, y_ref, *, window: int, block_e: int):
 
     vals = vals_ref[...].astype(jnp.float32)     # [block_e]
     segs = segs_ref[...]                         # [block_e] int32, sorted
-    win_start = (jnp.min(jnp.where(segs >= 0, segs, 2 ** 30)) // 128) * 128
+    lo = jnp.min(jnp.where(segs >= 0, segs, 2 ** 30))
+    # an all-padding tile adds zeros: park its window at row 0
+    win_start = jnp.where(lo == 2 ** 30, 0, (lo // 128) * 128)
     local = segs - win_start
     oh = (jax.lax.broadcasted_iota(jnp.int32, (block_e, window), 1)
           == local[:, None])
@@ -35,16 +37,16 @@ def _segsum_kernel(vals_ref, segs_ref, y_ref, *, window: int, block_e: int):
     partial = jax.lax.dot_general(
         vals[None, :], oh.astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)[0]   # [window]
-    cur = pl.load(y_ref, (pl.ds(win_start, window),))
-    pl.store(y_ref, (pl.ds(win_start, window),), cur + partial)
+    y_ref[pl.ds(win_start, window)] += partial
 
 
 def segment_sum_sorted(vals: jnp.ndarray, segs: jnp.ndarray, n_out: int, *,
                        block_e: int = 512, window: int = 1024,
                        interpret: bool = False) -> jnp.ndarray:
     """vals [E] fp, segs [E] int32 sorted ascending (−1 ⇒ dropped), padded to
-    a multiple of ``block_e``; output [n_out_padded] fp32 where n_out is
-    rounded up to window alignment by the caller (ops wrapper)."""
+    a multiple of ``block_e``; output [n_out_padded] fp32 where the caller
+    (ops wrapper) pads n_out one window past the last segment, so a window
+    opened at any segment stays in bounds."""
     E = vals.shape[0]
     assert E % block_e == 0, (E, block_e)
     assert n_out % window == 0, (n_out, window)

@@ -88,13 +88,15 @@ class SageTrainer:
         return float(l)
 
     # -------------------------------------------------- device-resident path
-    def _device_step_fn(self, params, step, seeds):
+    def _device_step_fn(self, params, tables, step, seeds):
         """sample → gather → SGD as one traced program (DESIGN.md §10).
         The per-step key folds INSIDE the jit — an eager fold_in costs more
-        than the whole sampled batch on CPU."""
+        than the whole sampled batch on CPU. The device tables are an
+        argument: closed over, the feature matrix would bake into the
+        program as a constant."""
         key = jax.random.fold_in(self._base_key, step)
         layers, feats, labels = self._executor._sample_impl(
-            self._executor._tables, seeds, key, self.fanouts)
+            tables, seeds, key, self.fanouts)
 
         def loss(p):
             return self.model.loss(p, feats, layers, labels)
@@ -109,8 +111,9 @@ class SageTrainer:
         rng = np.random.default_rng(step)
         seeds = rng.integers(0, self._executor.n_vertices,
                              self.batch_size).astype(np.int32)
-        self.params, l = self._device_step(self.params, np.uint32(step),
-                                           seeds)
+        self.params, l = self._device_step(self.params,
+                                           self._executor._tables,
+                                           np.uint32(step), seeds)
         return float(l)
 
     def train(self, steps: int, pipelined: bool = True,
@@ -162,9 +165,9 @@ class SageTrainer:
         if cached is not None and cached[0] is ex:
             return cached[1]
 
-        def score(params, base_key, i, seeds):
+        def score(params, tables, base_key, i, seeds):
             key = jax.random.fold_in(base_key, i)
-            layers, feats, _ = ex._sample_impl(ex._tables, seeds, key,
+            layers, feats, _ = ex._sample_impl(tables, seeds, key,
                                                self.fanouts)
             lg = self.model.logits(params, feats, layers)
             return jnp.max(lg, axis=-1)          # max-logit confidence
@@ -194,7 +197,7 @@ class SageTrainer:
             hi = min(lo + chunk, n)
             seeds = np.full(chunk, -1, np.int32)
             seeds[:hi - lo] = np.arange(lo, hi)
-            s = fn(params, base, np.uint32(i), seeds)
+            s = fn(params, ex._tables, base, np.uint32(i), seeds)
             out[lo:hi] = np.asarray(s)[:hi - lo]
         return out
 

@@ -25,6 +25,16 @@ class TestPregel:
         ref = alg.pagerank_numpy(indptr, indices, iters=30)
         np.testing.assert_allclose(pr, ref, rtol=1e-4, atol=1e-7)
 
+    def test_memoized_fixpoint_honours_its_bounds(self, graph):
+        """A later call with other loop bounds must not reuse the first
+        call's compiled fixpoint."""
+        eng = GrapeEngine(graph)
+        short = np.asarray(alg.pagerank(eng, max_steps=2, tol=0.0))
+        full = np.asarray(alg.pagerank(eng, max_steps=30))
+        fresh = np.asarray(alg.pagerank(GrapeEngine(graph), max_steps=30))
+        np.testing.assert_array_equal(full, fresh)
+        assert not np.array_equal(short, full)
+
     def test_pagerank_fragments_invariant(self, graph):
         e1 = GrapeEngine(graph, n_frags=1)
         e3 = GrapeEngine(graph, n_frags=3)

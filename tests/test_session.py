@@ -483,6 +483,18 @@ class TestSessionSurface:
         with pytest.raises(TypeError):
             flexbuild(store, ["cypher"], batch_size=8)   # needs serve=True
 
+    def test_flexbuild_serve_refuses_mesh(self):
+        """The session path takes no mesh: asking for one must fail
+        loudly, never serve single-device in silence."""
+        import jax
+
+        mesh = jax.make_mesh((1,), ("data",))
+        with pytest.raises(TypeError, match="mesh"):
+            flexbuild(small_gart(), ["cypher", "grape"], mesh=mesh,
+                      serve=True)
+        dep = flexbuild(small_gart(), ["grape"], mesh=mesh)
+        assert dep.engine("grape").mesh is mesh
+
     def test_gremlin_write_through_session(self):
         s = FlexSession(small_gart())
         r = s.execute("g.V().has('id', $v).add_e('KNOWS', $d)"

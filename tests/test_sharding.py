@@ -54,7 +54,7 @@ class TestLogicalSpecs:
 
 _SUBPROCESS_TEMPLATE = textwrap.dedent("""
     import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n}"
     os.environ["JAX_PLATFORMS"] = "cpu"
     import sys
     sys.path.insert(0, {src!r})
@@ -65,9 +65,10 @@ _SUBPROCESS_TEMPLATE = textwrap.dedent("""
 """)
 
 
-def run_sub(body: str) -> dict:
+def run_sub(body: str, n_devices: int = 8) -> dict:
     code = _SUBPROCESS_TEMPLATE.format(src=os.path.abspath(SRC),
-                                       body=textwrap.dedent(body))
+                                       body=textwrap.dedent(body),
+                                       n=n_devices)
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
@@ -136,6 +137,34 @@ class TestMultiDevice:
             print(json.dumps({'diff': float(np.abs(p1 - p2).max())}))
         """)
         assert r["diff"] < 1e-5
+
+    @pytest.mark.parametrize("combiner", ["sum", "min", "max"])
+    def test_grape_superstep_replicated_on_four_devices(self, combiner):
+        """The sharded superstep hands back the collective's result as one
+        unsharded [N] vector, equal to the single-device stacked form."""
+        r = run_sub(f"""
+            from repro.storage.generators import rmat_store
+            from repro.engines.grape import GrapeEngine
+
+            g = rmat_store(scale=8, edge_factor=4, seed=3)
+            mesh = jax.make_mesh((4,), ('data',))
+            e_local = GrapeEngine(g, n_frags=4)
+            e_dist = GrapeEngine(g, n_frags=4, mesh=mesh)
+            vals = jnp.asarray(np.random.default_rng(0).random(
+                g.n_vertices).astype(np.float32))
+            want = e_local.superstep(e_local.owned_view(vals),
+                                     {combiner!r}, use_weights=True)
+            got = e_dist.superstep(e_dist.owned_view(vals),
+                                   {combiner!r}, use_weights=True)
+            print(json.dumps({{
+                'shape': list(got.shape), 'n': g.n_vertices,
+                'replicated': bool(got.sharding.is_fully_replicated),
+                'equal': bool(np.allclose(np.asarray(got),
+                                          np.asarray(want), rtol=1e-6))}}))
+        """, n_devices=4)
+        assert r["shape"] == [r["n"]]
+        assert r["replicated"]
+        assert r["equal"]
 
     def test_elastic_checkpoint_reshard(self):
         r = run_sub("""
