@@ -376,16 +376,22 @@ class FragmentSampleExecutor:
 
     # ------------------------------------------------------------- batch
     def _sample_impl(self, tables, seeds, key, fanouts: Tuple[int, ...]):
+        # device scopes (DESIGN.md §10): a profile names each op by the
+        # part of the batch it belongs to, whatever XLA's fusion numbering
         frontiers = [seeds.astype(jnp.int32)]
         layers = []
         for l, k in enumerate(fanouts):
-            u = layer_uniforms(key, l, frontiers[-1].shape[0], k)
-            nbrs = self._layer(tables, frontiers[-1], u)
+            with jax.named_scope(f"sample.hop{l}"):
+                u = layer_uniforms(key, l, frontiers[-1].shape[0], k)
+                nbrs = self._layer(tables, frontiers[-1], u)
             layers.append(nbrs)
             frontiers.append(nbrs.reshape(-1))
-        feats = [self._gather(tables["feats"], fr) for fr in frontiers]
-        labels = (self._gather(tables["labels"], frontiers[0])
-                  if tables["labels"] is not None else None)
+        with jax.named_scope("gather.features"):
+            feats = [self._gather(tables["feats"], fr) for fr in frontiers]
+        labels = None
+        if tables["labels"] is not None:
+            with jax.named_scope("gather.labels"):
+                labels = self._gather(tables["labels"], frontiers[0])
         return layers, feats, labels
 
     def sample(self, seeds, key, fanouts: Sequence[int]):

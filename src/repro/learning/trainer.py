@@ -25,6 +25,7 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.learning.gnn import GraphSAGE
 from repro.learning.pipeline import DecoupledPipeline
@@ -73,14 +74,20 @@ class SageTrainer:
             "labels": b.labels.astype(np.int32),
         }
 
-    def _update_fn(self, params, feats, nbrs, labels):
-        def loss(p):
-            return self.model.loss(p, feats, nbrs, labels)
-
-        l, g = jax.value_and_grad(loss)(params)
-        params = jax.tree_util.tree_map(lambda p, gg: p - self.lr * gg,
-                                        params, g)
+    def _sgd(self, params, loss):
+        """One SGD step on ``loss(params)`` → (params, loss), under the
+        device scopes ``model.fwd_bwd`` (the backward ops carry it too)
+        and ``model.update``."""
+        with jax.named_scope("model.fwd_bwd"):
+            l, g = jax.value_and_grad(loss)(params)
+        with jax.named_scope("model.update"):
+            params = jax.tree_util.tree_map(lambda p, gg: p - self.lr * gg,
+                                            params, g)
         return params, l
+
+    def _update_fn(self, params, feats, nbrs, labels):
+        return self._sgd(params, lambda p: self.model.loss(p, feats, nbrs,
+                                                            labels))
 
     def train_on(self, batch) -> float:
         self.params, l = self._update(self.params, batch["feats"],
@@ -97,24 +104,26 @@ class SageTrainer:
         key = jax.random.fold_in(self._base_key, step)
         layers, feats, labels = self._executor._sample_impl(
             tables, seeds, key, self.fanouts)
-
-        def loss(p):
-            return self.model.loss(p, feats, layers, labels)
-
-        l, g = jax.value_and_grad(loss)(params)
-        params = jax.tree_util.tree_map(lambda p, gg: p - self.lr * gg,
-                                        params, g)
-        return params, l
+        return self._sgd(params, lambda p: self.model.loss(p, feats, layers,
+                                                            labels))
 
     def train_step_device(self, step: int) -> float:
-        # same per-step seed schedule as the numpy path's ``sample``
-        rng = np.random.default_rng(step)
-        seeds = rng.integers(0, self._executor.n_vertices,
-                             self.batch_size).astype(np.int32)
-        self.params, l = self._device_step(self.params,
-                                           self._executor._tables,
-                                           np.uint32(step), seeds)
-        return float(l)
+        """One fused step; its loss on the host. Host spans (DESIGN.md
+        §10): ``flex.learning.step`` around the call, and inside it the
+        seed draw, the dispatch of the jitted step (a compile or a cache
+        load lands there) and the wait for the loss."""
+        with StepTraceAnnotation("flex.learning.step", step_num=step):
+            with TraceAnnotation("flex.learning.seeds"):
+                # same per-step seed schedule as the numpy path's ``sample``
+                rng = np.random.default_rng(step)
+                seeds = rng.integers(0, self._executor.n_vertices,
+                                     self.batch_size).astype(np.int32)
+            with TraceAnnotation("flex.learning.dispatch"):
+                self.params, l = self._device_step(self.params,
+                                                   self._executor._tables,
+                                                   np.uint32(step), seeds)
+            with TraceAnnotation("flex.learning.loss_wait"):
+                return float(l)
 
     def train(self, steps: int, pipelined: bool = True,
               n_workers: int = 2, prefetch: str = "host"

@@ -242,6 +242,61 @@ class TestDeviceTraining:
         assert la == lb
 
 
+class TestStepTracing:
+    """The fused step names its parts (DESIGN.md §10): device scopes in
+    the compiled program's op_name metadata, host spans in a profile."""
+
+    @pytest.fixture(scope="class")
+    def trainer(self, featured_graph):
+        return SageTrainer(
+            GraphSampler(featured_graph, label_prop="label",
+                         backend="device"),
+            hidden=16, n_classes=2, fanouts=[4, 3, 2], batch_size=32,
+            backend="device")
+
+    def test_device_step_carries_scopes(self, trainer):
+        import re
+
+        text = trainer._device_step.lower(
+            trainer.params, trainer._executor._tables, np.uint32(0),
+            np.zeros(32, np.int32)).compile().as_text()
+        scopes = {part for name in re.findall(r'op_name="([^"]*)"', text)
+                  for part in name.split("/")}
+        assert {"sample.hop0", "sample.hop1", "sample.hop2",
+                "gather.features", "gather.labels", "model.fwd_bwd",
+                "model.update"} <= scopes
+
+    def test_step_host_spans_nest_in_order(self, trainer, tmp_path):
+        import glob
+
+        from jax.profiler import ProfileData
+
+        trainer.train_step_device(0)                # compile outside
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            for step in (1, 2):
+                trainer.train_step_device(step)
+        finally:
+            jax.profiler.stop_trace()
+        path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        # by start, an enclosing span before the spans it holds
+        spans = sorted(((ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+                        for plane in ProfileData.from_file(path).planes
+                        for ln in plane.lines for ev in ln.events
+                        if ev.name.startswith("flex.")),
+                       key=lambda sp: (sp[0], -sp[1]))
+        inner = ["flex.learning.seeds", "flex.learning.dispatch",
+                 "flex.learning.loss_wait"]
+        assert [n for _, _, n in spans] == (["flex.learning.step"]
+                                            + inner) * 2
+        for i in (0, 4):
+            s0, e0, _ = spans[i]
+            kids = spans[i + 1:i + 4]
+            assert all(s0 <= s <= e <= e0 for s, e, _ in kids)
+            assert all(a[1] <= b[0] for a, b in zip(kids, kids[1:]))
+
+
 class TestReviewRegressions:
     def test_device_prefetch_descends_into_sampled_batch(self, featured_graph):
         """SampledBatch is a plain dataclass, not a registered pytree:
