@@ -36,11 +36,15 @@ differential suite pins against the numpy ``sampler_ref`` oracle.
 
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax._src import config as jax_config
+from jax.experimental.layout import Format, Layout
+from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.sampler import (SLAB_VMEM_BYTES, csr_to_sample_ell,
                                    layer_uniforms, sample_csr_jnp,
@@ -55,6 +59,46 @@ EXCHANGES = ("stacked", "psum")
 # model it is O(N·d_max)); beyond this, construction refuses with a pointer
 # at the O(E) stacked path rather than OOM-ing mid-__init__
 PSUM_SLAB_LIMIT_BYTES = 2 ** 31
+
+
+def resident_table(table) -> jax.Array:
+    """One device's copy of a per-vertex table, row-major: the layout its
+    row gathers read (DESIGN.md §10).
+
+    A TPU's default layout for a narrow float table is column-major
+    (``f32[n, 100]`` pads 100 to 104 where row-major pads it to 128), and
+    a jitted gather over an argument in that layout relays the whole table
+    out on every call. A committed array carries its layout into every jit
+    that takes it, so the table is placed once in the layout the gathers
+    read: one host-to-device transfer, relaid out on the device by a small
+    program that keeps only the row-major copy. ``table`` is a host array
+    (its layout is the device's default) or a device array (its own); one
+    that is already row-major, as on CPU, is left as ``jnp.asarray``
+    places it."""
+    if isinstance(table, jax.Array):
+        device, = table.devices()
+        layout = table.format.layout
+    else:
+        device = jax.devices()[0]
+        layout = Layout.from_pjrt_layout(device.client.get_default_layout(
+            table.dtype, table.shape, device))
+    rows = tuple(range(table.ndim))
+    if layout.major_to_minor == rows:
+        return jnp.asarray(table)
+    # JAX 0.9 drops an executable's output layouts when it loads one from
+    # the persistent compilation cache, so a cached relayout would hand
+    # back a table in (or labelled with) the default layout. The relayout
+    # compiles in milliseconds: it is never written there (a thread-local
+    # setting, read when an entry would be written), and its program is
+    # named for this function, so no cached program of another caller (a
+    # plain ``jax.device_put`` runs the same identity) is ever read for it
+    with jax_config.persistent_cache_min_compile_time_secs(math.inf):
+        return jax.jit(_relayout_rows, out_shardings=Format(
+            Layout(rows), SingleDeviceSharding(device)))(table)
+
+
+def _relayout_rows(table):
+    return table
 
 
 class FragmentSampleExecutor:
@@ -145,7 +189,9 @@ class FragmentSampleExecutor:
                     f_lab[f, :hi - lo] = lab[lo:hi]
             self.ell = jnp.asarray(f_ell)
             self.deg = jnp.asarray(f_deg)
-            self.feats = jnp.asarray(f_feat)
+            # under a mesh the table is resharded inside shard_map
+            self.feats = (jnp.asarray(f_feat) if mesh is not None
+                          else resident_table(f_feat))
             self.labels = None if f_lab is None else jnp.asarray(f_lab)
             self.starts = jnp.arange(F, dtype=jnp.int32) * vp
         else:
@@ -169,7 +215,7 @@ class FragmentSampleExecutor:
                     [indices, [PAD_SENTINEL]]).astype(np.int32))
             feats_pad = np.zeros((n + 1, self.feature_dim), np.float32)
             feats_pad[:n] = feats
-            self.feats = jnp.asarray(feats_pad)
+            self.feats = resident_table(feats_pad)
             self.labels = None
             if lab is not None:
                 lab_pad = np.zeros(n + 1, np.int32)

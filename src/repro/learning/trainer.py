@@ -32,6 +32,16 @@ from repro.learning.pipeline import DecoupledPipeline
 from repro.learning.sampler import GraphSampler
 
 
+def _on_table_device(fn, ex):
+    """``jax.jit(fn)`` with every argument on the device of ``ex``'s
+    feature table. A table placed in its own layout is committed
+    (``engines/sample.py`` ``resident_table``), so the program's outputs
+    are too: without a fixed placement, parameters fresh from ``init``
+    (uncommitted) and the ones a step returns would lower to two programs,
+    and the first step would compile or load a program of its own."""
+    return jax.jit(fn, in_shardings=ex.feats.sharding)
+
+
 class SageTrainer:
     def __init__(self, sampler: GraphSampler, hidden: int, n_classes: int,
                  fanouts: Sequence[int], batch_size: int = 256,
@@ -61,7 +71,8 @@ class SageTrainer:
             if sampler.label_prop is None:
                 raise ValueError("backend='device' training needs the "
                                  "sampler's label_prop")
-            self._device_step = jax.jit(self._device_step_fn)
+            self._device_step = _on_table_device(self._device_step_fn,
+                                                 self._executor)
 
     def sample(self, step: int) -> Dict[str, np.ndarray]:
         n = self.sampler.grin.n_vertices
@@ -181,7 +192,7 @@ class SageTrainer:
             lg = self.model.logits(params, feats, layers)
             return jnp.max(lg, axis=-1)          # max-logit confidence
 
-        fn = jax.jit(score)
+        fn = _on_table_device(score, ex)
         self._infer_runners[id(ex)] = (ex, fn)
         return fn
 

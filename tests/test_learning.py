@@ -297,6 +297,215 @@ class TestStepTracing:
             assert all(a[1] <= b[0] for a, b in zip(kids, kids[1:]))
 
 
+def _column_major(table):
+    """A TPU's default layout for a narrow table, built by hand: CPU
+    places ``f32[n, D]`` row-major, a TPU column-major."""
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import SingleDeviceSharding
+
+    device, = table.devices()
+    return jax.device_put(table, Format(Layout(tuple(reversed(range(
+        table.ndim)))), SingleDeviceSharding(device)))
+
+
+def _with_feats(ex, feats):
+    ex.feats = feats
+    ex._tables = ex._make_tables()
+    return ex
+
+
+class TestResidentTableLayout:
+    """The feature table is placed once in the row-major layout its gathers
+    read (``engines/sample.py`` ``resident_table``; DESIGN.md §10), so the
+    fused step takes it as it is and never relays it out."""
+
+    @staticmethod
+    def _trainer(graph, feats_layout=None):
+        tr = SageTrainer(GraphSampler(graph, label_prop="label",
+                                      backend="device"),
+                         hidden=16, n_classes=2, fanouts=[4, 3, 2],
+                         batch_size=32, lr=0.05, seed=3, backend="device")
+        if feats_layout is not None:
+            _with_feats(tr._executor, feats_layout(tr._executor.feats))
+        return tr
+
+    @staticmethod
+    def _step_text(tr):
+        return tr._device_step.lower(
+            tr.params, tr._executor._tables, np.uint32(0),
+            np.zeros(32, np.int32)).compile().as_text()
+
+    @staticmethod
+    def _table_layout_and_copies(tr):
+        import re
+
+        text = TestResidentTableLayout._step_text(tr)
+        n, d = tr._executor.feats.shape
+        shape = re.escape(f"f32[{n},{d}]")
+        entry = re.search(r"entry_computation_layout=\{\((.*?)\)->",
+                          text).group(1)
+        layouts = re.findall(shape + r"(\{[^}]*\})", entry)
+        copies = re.findall(shape + r"\S* copy\(", text)
+        return layouts, copies
+
+    @pytest.mark.parametrize("exchange", ["stacked", "psum"])
+    def test_placement_makes_column_major_row_major(self, featured_graph,
+                                                    exchange):
+        from repro.engines.sample import (FragmentSampleExecutor,
+                                          resident_table)
+
+        ex = FragmentSampleExecutor(featured_graph, n_frags=2,
+                                    exchange=exchange)
+        cm = _column_major(ex.feats)
+        assert cm.format.layout.major_to_minor[-1] == 0
+        rm = resident_table(cm)
+        assert rm.format.layout.major_to_minor == tuple(range(rm.ndim))
+        np.testing.assert_array_equal(np.asarray(rm), np.asarray(ex.feats))
+
+    def test_host_table_keeps_default_row_major_layout(self, featured_graph):
+        """On CPU the default is already row-major: the placement leaves the
+        table as ``jnp.asarray`` puts it, uncommitted."""
+        tr = self._trainer(featured_graph)
+        feats = tr._executor.feats
+        assert feats.format.layout.major_to_minor == (0, 1)
+        assert not feats.committed
+
+    def test_step_reads_row_major_table_without_copy(self, featured_graph):
+        from repro.engines.sample import resident_table
+
+        rm = self._trainer(featured_graph,
+                           lambda t: resident_table(_column_major(t)))
+        assert rm._executor.feats.format.layout.major_to_minor == (0, 1)
+        layouts, copies = self._table_layout_and_copies(rm)
+        assert layouts == ["{1,0}"] and copies == []
+        # the regression the placement removes: a column-major argument
+        # is relaid out inside the step
+        cm = self._trainer(featured_graph, _column_major)
+        layouts, copies = self._table_layout_and_copies(cm)
+        assert layouts == ["{0,1}"] and copies
+
+    def test_layouts_give_bit_identical_batches_and_steps(self,
+                                                          featured_graph):
+        from repro.engines.sample import resident_table
+
+        rm = self._trainer(featured_graph,
+                           lambda t: resident_table(_column_major(t)))
+        cm = self._trainer(featured_graph, _column_major)
+        seeds = np.random.default_rng(5).integers(
+            0, featured_graph.n_vertices, 32)
+        key = jax.random.PRNGKey(7)
+        for a, b in zip(jax.tree_util.tree_leaves(
+                rm._executor.sample(seeds, key, (4, 3, 2))),
+                jax.tree_util.tree_leaves(
+                    cm._executor.sample(seeds, key, (4, 3, 2)))):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        for step in range(3):
+            assert rm.train_step_device(step) == cm.train_step_device(step)
+        for a, b in zip(jax.tree_util.tree_leaves(rm.params),
+                        jax.tree_util.tree_leaves(cm.params)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_placement_is_not_written_to_persistent_compile_cache(
+            self, tmp_path):
+        """JAX 0.9 loads a cached program's outputs in the default layout,
+        so the relayout the placement runs must never be written to the
+        persistent compile cache: a later process would read it back and
+        get a column-major table labelled row-major on a TPU (on CPU the
+        default is the target, so only the cache's contents show it)."""
+        import json
+        import os
+        import subprocess
+        import sys
+
+        script = """if True:
+            import json, os
+            import jax, jax.numpy as jnp, numpy as np
+            from jax.experimental.layout import Format, Layout
+            from jax.sharding import SingleDeviceSharding
+            from repro.compile_cache import configure_compile_cache
+            from repro.engines.sample import resident_table
+            cache = configure_compile_cache()
+            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+            x = np.arange(40 * 16, dtype=np.float32).reshape(40, 16)
+            cm = jax.jit(lambda a: a, out_shardings=Format(
+                Layout((1, 0)), SingleDeviceSharding(jax.devices()[0])))(x)
+            before = set(os.listdir(cache))
+            rm = resident_table(cm)
+            by_put = set(os.listdir(cache)) - before
+            i = np.array([3, 0, 39, 7], np.int32)
+            got = jax.jit(lambda t, i: jnp.take(t, i, axis=0))(rm, i)
+            print(json.dumps({
+                "in": cm.format.layout.major_to_minor,
+                "out": rm.format.layout.major_to_minor,
+                "rows": bool(np.array_equal(np.asarray(got), x[i])),
+                "by_put": len(by_put),
+                "by_gather": len(set(os.listdir(cache)) - before - by_put)}))
+        """
+        src = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "src")
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+                   PYTHONPATH=src + os.pathsep + os.environ.get(
+                       "PYTHONPATH", ""))
+        out = subprocess.run([sys.executable, "-c", script], env=env,
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert json.loads(out.stdout.splitlines()[-1]) == {
+            "in": [1, 0], "out": [0, 1], "rows": True, "by_put": 0,
+            "by_gather": 1}
+
+    def test_fresh_and_stepped_params_share_one_program(self,
+                                                        featured_graph):
+        """The committed table makes the step's outputs committed; the step
+        and the ``gnn.infer`` runner still lower to one program for fresh
+        (uncommitted) parameters and for the ones a step returns."""
+        from repro.engines.sample import resident_table
+
+        tr = self._trainer(featured_graph,
+                           lambda t: resident_table(_column_major(t)))
+        ex = tr._executor
+        fresh = tr.params
+        tr.train_step_device(0)
+        stepped = tr.params
+        assert jax.tree_util.tree_leaves(stepped)[0].committed
+        step = lambda p: tr._device_step.lower(
+            p, ex._tables, np.uint32(1), np.zeros(32, np.int32)).as_text()
+        assert step(fresh) == step(stepped)
+        run = tr._infer_runner(ex)
+        infer = lambda p: run.lower(
+            p, ex._tables, jax.random.PRNGKey(0), np.uint32(0),
+            np.zeros(tr.INFER_CHUNK, np.int32)).as_text()
+        assert infer(fresh) == infer(stepped)
+
+    def test_advance_carries_table_and_infer_compiles_once(self):
+        from repro.engines.sample import resident_table
+        from repro.storage.gart import GARTStore
+
+        rng = np.random.default_rng(13)
+        n, e = 150, 700
+        g = GARTStore.from_csr(CSRStore(
+            n, rng.integers(0, n, e), rng.integers(0, n, e),
+            vertex_props={"feat": rng.random((n, 8)).astype(np.float32),
+                          "label": rng.integers(0, 2, n),
+                          "age": rng.integers(0, 90, n)}))
+        tr = self._trainer(g.snapshot(),
+                           lambda t: resident_table(_column_major(t)))
+        ex0 = tr._executor
+        before = tr.infer_scores()
+        np.testing.assert_array_equal(tr.infer_scores(), before)
+        assert tr._infer_runners[id(ex0)][1]._cache_size() == 1
+        v0 = g.write_version
+        g.set_vertex_prop("age", np.array([3, 9]), np.array([1, 2]))
+        ex1 = ex0.advance(g.snapshot(), g.commit_delta(v0))
+        assert ex1 is not None and ex1._tables["feats"] is ex0.feats
+        assert ex1.feats.format.layout.major_to_minor == (0, 1)
+        tr.sampler._device = ex1
+        np.testing.assert_array_equal(tr.infer_scores(), before)
+        np.testing.assert_array_equal(tr.infer_scores(), before)
+        assert tr._infer_runners[id(ex1)][1]._cache_size() == 1
+
+
 class TestReviewRegressions:
     def test_device_prefetch_descends_into_sampled_batch(self, featured_graph):
         """SampledBatch is a plain dataclass, not a registered pytree:

@@ -146,38 +146,72 @@ class TestServedPathCompiles:
     def test_fused_sage_train_step(self, one_chip):
         """sample → gather → SGD as one program (DESIGN.md §10) at feature
         width 100 over the served graph's vertex and edge counts."""
-        from repro.learning.sampler import GraphSampler
-        from repro.learning.trainer import SageTrainer
-        from repro.storage.generators import snb_store
+        _compile(*_sage_step(one_chip, one_chip, N_SNB, E_SNB, FANOUTS))
 
-        small = snb_store(n_persons=64, n_items=32, n_posts=16, seed=0)
-        rng = np.random.default_rng(0)
-        small._vprops["feat"] = rng.standard_normal(
-            (small.n_vertices, FEAT_DIM)).astype(np.float32)
-        small._vprops["label"] = rng.integers(
-            0, N_CLASSES, small.n_vertices).astype(np.int32)
-        tr = SageTrainer(GraphSampler(small, label_prop="label",
-                                      backend="device"),
-                         hidden=HIDDEN, n_classes=N_CLASSES,
-                         fanouts=FANOUTS, batch_size=SAGE_BATCH,
-                         backend="device")
-        # the same program over the real vertex count
-        tr = copy.copy(tr)
-        tr._executor = copy.copy(tr._executor)
-        tr._executor.n_vertices = N_SNB
-        tables = {"ell": None, "starts": None,
-                  "deg": jax.ShapeDtypeStruct((N_SNB,), jnp.int32),
-                  "feats": jax.ShapeDtypeStruct((N_SNB + 1, FEAT_DIM),
-                                                jnp.float32),
-                  "labels": jax.ShapeDtypeStruct((N_SNB + 1,), jnp.int32),
-                  "csr_starts": jax.ShapeDtypeStruct((N_SNB,), jnp.int32),
-                  "csr_indices": jax.ShapeDtypeStruct((E_SNB + 1,),
-                                                      jnp.int32)}
-        _compile(tr._device_step_fn, _sds(tr.params, one_chip),
-                 _sds(tables, one_chip),
-                 jax.ShapeDtypeStruct((), jnp.uint32, sharding=one_chip),
-                 jax.ShapeDtypeStruct((SAGE_BATCH,), jnp.int32,
-                                      sharding=one_chip))
+    @pytest.mark.parametrize("row_major", [False, True])
+    def test_sage_step_reads_resident_table_layout(self, one_chip,
+                                                   row_major):
+        """Over ogbn-products' vertex and arc counts the chip's default
+        layout for the feature table is column-major, and the step then
+        copies the whole table into the row-major layout its gathers read;
+        given the table in that layout (``engines/sample.py``
+        ``resident_table``), it copies nothing."""
+        import re
+
+        from jax.experimental.layout import Format, Layout
+
+        n, e = 2_449_029, 123_718_280
+        device, = one_chip.device_set
+        default = Layout.from_pjrt_layout(device.client.get_default_layout(
+            np.dtype(np.float32), (n + 1, FEAT_DIM), device))
+        assert default.major_to_minor == (1, 0)
+        table = (Format(Layout((0, 1)), one_chip) if row_major
+                 else one_chip)
+        hlo = _compile(*_sage_step(one_chip, table, n, e, FANOUTS))
+        shape = re.escape(f"f32[{n + 1},{FEAT_DIM}]")
+        entry = re.search(r"entry_computation_layout=\{\((.*?)\)->",
+                          hlo).group(1)
+        assert re.findall(shape + r"\{(\d),(\d)", entry) == [
+            ("1", "0") if row_major else ("0", "1")]
+        assert bool(re.findall(shape + r"\S* copy\(", hlo)) != row_major
+
+
+def _sage_step(one_chip, feats_sharding, n, e, fanouts):
+    """The fused GraphSAGE step and its argument shapes over ``n`` vertices
+    and ``e`` arcs, its feature table placed by ``feats_sharding`` (a
+    sharding or a format)."""
+    from repro.learning.sampler import GraphSampler
+    from repro.learning.trainer import SageTrainer
+    from repro.storage.generators import snb_store
+
+    small = snb_store(n_persons=64, n_items=32, n_posts=16, seed=0)
+    rng = np.random.default_rng(0)
+    small._vprops["feat"] = rng.standard_normal(
+        (small.n_vertices, FEAT_DIM)).astype(np.float32)
+    small._vprops["label"] = rng.integers(
+        0, N_CLASSES, small.n_vertices).astype(np.int32)
+    tr = SageTrainer(GraphSampler(small, label_prop="label",
+                                  backend="device"),
+                     hidden=HIDDEN, n_classes=N_CLASSES,
+                     fanouts=fanouts, batch_size=SAGE_BATCH,
+                     backend="device")
+    # the same program over the real vertex count
+    tr = copy.copy(tr)
+    tr._executor = copy.copy(tr._executor)
+    tr._executor.n_vertices = n
+    tables = {"ell": None, "starts": None,
+              "deg": jax.ShapeDtypeStruct((n,), jnp.int32),
+              "feats": jax.ShapeDtypeStruct((n + 1, FEAT_DIM), jnp.float32),
+              "labels": jax.ShapeDtypeStruct((n + 1,), jnp.int32),
+              "csr_starts": jax.ShapeDtypeStruct((n,), jnp.int32),
+              "csr_indices": jax.ShapeDtypeStruct((e + 1,), jnp.int32)}
+    tables = _sds(tables, one_chip)
+    tables["feats"] = jax.ShapeDtypeStruct((n + 1, FEAT_DIM), jnp.float32,
+                                           sharding=feats_sharding)
+    return (tr._device_step_fn, _sds(tr.params, one_chip), tables,
+            jax.ShapeDtypeStruct((), jnp.uint32, sharding=one_chip),
+            jax.ShapeDtypeStruct((SAGE_BATCH,), jnp.int32,
+                                 sharding=one_chip))
 
 
 class TestKernelsCompile:
