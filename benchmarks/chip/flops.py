@@ -4,7 +4,9 @@ Kept with the benchmark so that every PR counts the same way. Of the
 operations only the matrix products count: sampling, gathers, the
 neighbour mean and the loss are left out, so a share of the peak
 computed from these counts is a lower bound of what the step issues.
-Of the bytes only the feature rows a step must read count.
+Of the bytes only the feature rows a step must read count, and of a
+GRAPE superstep only its arcs' targets and values and its vertices'
+state.
 """
 
 from __future__ import annotations
@@ -52,6 +54,14 @@ def sage_gather_bytes(batch: int, fanouts: Sequence[int],
         rows *= f
         total += rows
     return total * feature_dim * 4
+
+
+def grape_superstep_bytes(n_vertices: int, arcs: int) -> int:
+    """Bytes one GRAPE superstep has to move: each arc's target id and
+    value (4 B each), and each vertex's state read and written (4 B
+    each). The arcs' sources, the mask and the message buffer's
+    read-modify-write are not counted."""
+    return 8 * arcs + 8 * n_vertices
 
 
 def peak(device_kind: str, what: str = "bf16_flops_per_s") -> float:

@@ -13,9 +13,10 @@ so ``op_paths`` reads those from the serialized trace itself.
 ``reduce`` reads the same ``.xplane.pb`` as ``trace.reduce``, over the same
 window (the host span ``bench.window``) and the same device ops, and adds:
 
-- ``scope_s``: device seconds in the window by the first component of the
-  first scope name in each op's path (``sample``, ``gather``, ``model``),
-  each op counted once; ops with no scope under ``unscoped``;
+- ``scope_s`` and ``scope_n``: device seconds and op events in the
+  window by the first component of the first scope name in each op's
+  path (``sample``, ``gather``, ``model``), each op counted once, both
+  averaged over the device planes; ops with no scope under ``unscoped``;
 - ``unscoped_ops``: the ten largest of those, by op name;
 - ``span_s`` and ``span_n``: seconds (clipped to the window) and count of
   each ``flex.*`` host span that overlaps the window;
@@ -154,8 +155,8 @@ def label_times(times: List[float], spans: List[Tuple[float, float, str]]
 
 def reduce_planes(planes, paths: Dict[str, Dict[str, str]]
                   ) -> Optional[Dict]:
-    """``scope_s``, ``unscoped_ops``, ``span_s``, ``span_n`` and
-    ``idle_gaps`` of one trace, its ops' paths given by ``op_paths``;
+    """``scope_s``, ``scope_n``, ``unscoped_ops``, ``span_s``, ``span_n``
+    and ``idle_gaps`` of one trace, its ops' paths given by ``op_paths``;
     None where it holds no window span or no device plane."""
     window = None
     spans: List[Tuple[float, float, str]] = []
@@ -182,6 +183,7 @@ def reduce_planes(planes, paths: Dict[str, Dict[str, str]]
     spans = [sp for sp in spans if sp[1] > w0 and sp[0] < w1]
     cuts = sorted({x for sp in spans for x in sp[:2]})
     scope_ns: Dict[str, float] = defaultdict(float)
+    scope_count: Dict[str, int] = defaultdict(int)
     unscoped_ns: Dict[str, float] = defaultdict(float)
     gap_ns: Dict[str, float] = defaultdict(float)
     for events in devices:
@@ -190,6 +192,7 @@ def reduce_planes(planes, paths: Dict[str, Dict[str, str]]
         for s, e, text, path in clipped:
             scope = scope_of(path)
             scope_ns[scope] += e - s
+            scope_count[scope] += 1
             if scope == UNSCOPED:
                 unscoped_ns[trace.op_name(text)] += e - s
         merged = trace.union((s, e) for s, e, _, _ in clipped)
@@ -211,6 +214,7 @@ def reduce_planes(planes, paths: Dict[str, Dict[str, str]]
     n = len(devices)
     return {
         "scope_s": {k: v / n / 1e9 for k, v in scope_ns.items()},
+        "scope_n": {k: v / n for k, v in scope_count.items()},
         "unscoped_ops": trace._top(unscoped_ns, n),
         "span_s": {k: v / 1e9 for k, v in span_ns.items()},
         "span_n": dict(span_n),

@@ -39,3 +39,11 @@ def test_peak_of_v5e():
 def test_unknown_device_kind_raises(kind):
     with pytest.raises(KeyError):
         flops.peak(kind)
+
+
+def test_grape_superstep_bytes():
+    # 3 arcs · (4 B target + 4 B value) + 2 vertices · (4 B read + 4 B write)
+    assert flops.grape_superstep_bytes(2, 3) == 40
+    # graph500_22 on four chips: 2^22 vertices, 128,311,468 arcs
+    assert flops.grape_superstep_bytes(2 ** 22, 128_311_468) == \
+        8 * 128_311_468 + 8 * 2 ** 22

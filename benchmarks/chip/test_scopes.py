@@ -218,3 +218,17 @@ def test_recorded_on_the_chip():
     # what trace files under bench.step goes to the program's spans
     flex = sum(s for g, s in out["idle_gaps"] if g.startswith("flex."))
     assert flex >= 0.9 * dict(base["idle_gaps"])["bench.step"]
+
+
+def test_trace_reduce_carries_the_scopes(step_trace_dir):
+    """``trace.reduce``'s summary, all a metric reader sees, holds the
+    seconds and op events by scope and the program's spans; its idle gaps
+    stay its own."""
+    out, named = trace.reduce(step_trace_dir), scopes.reduce(step_trace_dir)
+    for key in ("scope_s", "unscoped_ops", "span_s", "span_n"):
+        assert out[key] == named[key]
+    # fusion.9 twice, fusion.10 twice, fusion.3 three times (the last one
+    # half in the window), copy.50 once
+    assert out["scope_n"] == named["scope_n"] == {
+        "sample": 2, "gather": 2, "model": 3, "unscoped": 1}
+    assert [g for g, _ in out["idle_gaps"]] == ["bench.step"]

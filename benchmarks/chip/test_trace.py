@@ -6,9 +6,11 @@ import json
 import os
 from types import SimpleNamespace as NS
 
+import numpy as np
 import pytest
 
 from benchmarks.chip import trace
+from benchmarks.chip.harness import metric_reader
 
 RECORDED = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "testdata", "v5e_train_trace.json.gz")
@@ -80,3 +82,88 @@ def test_recorded_on_the_chip():
                            "fusion.8"]
     assert sum(out["op_s"].values()) >= out["busy_s"]
     assert [g for g, _ in out["idle_gaps"]] == ["bench.step"]
+
+
+# Two chips, two supersteps each in a window [0, 10000), inside one loop
+# (the while op, whose event spans its body's): a scatter over the
+# fragment's 6 arcs, the exchange of the 8-vertex buffer (the second
+# chip's is asynchronous: a start and a done), the update, and the
+# residual's scalar exchange, which is no superstep
+SCATTER = "%fusion.1 = f32[8]{0} fusion(s32[1,6]{1,0} %p0, f32[6]{0} %p1)"
+UPDATE = "%fusion.3 = f32[8]{0} fusion(f32[8]{0} %p0)"
+SYNC = "%all-reduce.2 = f32[8]{0} all-reduce(f32[8]{0} %fusion.1)"
+START = "%all-reduce-start.2 = f32[8]{0} all-reduce-start(f32[8]{0} %x)"
+DONE = "%all-reduce-done.2 = f32[8]{0} all-reduce-done(f32[8]{0} %y)"
+RESIDUAL = "%all-reduce.7 = f32[]{:T(128)} all-reduce(f32[]{:T(128)} %r)"
+LOOP = ("%while.5 = (f32[8]{0:T(1024)}, s32[1,6]{1,0:T(1,128)}) "
+        "while((f32[8]{0}, s32[1,6]{1,0}) %t), condition=%c, body=%b")
+GRAPE = [
+    _plane("/host:CPU", {"python": [("bench.window", 0, 10000),
+                                    ("bench.bfs", 0, 10000)]}),
+    _plane("/device:TPU:0", {"XLA Ops": [
+        (LOOP, 0, 7100),
+        (SCATTER, 0, 2000), (SYNC, 2000, 500), (UPDATE, 2500, 500),
+        (RESIDUAL, 3000, 100),
+        (SCATTER, 4000, 2000), (SYNC, 6000, 500), (UPDATE, 6500, 500),
+        (RESIDUAL, 7000, 100)]}),
+    _plane("/device:TPU:1", {"XLA Ops": [
+        (LOOP, 0, 7100),
+        (SCATTER, 0, 2400), (START, 2400, 100), (DONE, 2500, 300),
+        (UPDATE, 2800, 200), (RESIDUAL, 3000, 100),
+        (SCATTER, 4000, 2400), (START, 6400, 100), (DONE, 6500, 300),
+        (UPDATE, 6800, 200), (RESIDUAL, 7000, 100)]}),
+]
+
+
+def test_opcode():
+    assert trace.opcode(SCATTER) == "fusion"
+    assert trace.opcode(DONE) == "all-reduce-done"
+    assert trace.opcode(LOOP) == "while"
+
+
+def test_collectives_count_supersteps():
+    out = trace.reduce_planes(GRAPE)
+    assert out["op_n"] == {"fusion.1": 2, "all-reduce.2": 1,
+                           "all-reduce-start.2": 1, "all-reduce-done.2": 1,
+                           "fusion.3": 2, "all-reduce.7": 2, "while.5": 1}
+    # every exchange, a device: (2 · 500 + 2 · 100 + 2 · (100 + 300)
+    # + 2 · 100) / 2 ns, four calls
+    secs, calls = trace.collectives(out)
+    assert secs == pytest.approx(1100e-9)
+    assert calls == 4
+    # the buffer's exchange alone: one call a superstep
+    secs, calls = trace.collectives(out, "[8]")
+    assert secs == pytest.approx(900e-9)
+    assert calls == 2
+
+
+@pytest.mark.parametrize("metric, want", [
+    # 2 supersteps · 6 arcs · 8 B over 4400 ns a chip at 819 GB/s (the
+    # loop's own event is not the scatter's)
+    ("scatter_roofline", 100 * 2 * 6 * 8 / 4400e-9 / 819e9),
+    # 2 · (8 · 12 + 8 · 8) B over 10000 ns and 2 chips' 819 GB/s
+    ("superstep_hbm_share", 100 * 2 * 160 / 10000e-9 / (2 * 819e9)),
+    # 1100 of 7100 busy ns a chip
+    ("exchange_share", 1100 / 7100),
+    ("device_idle_share.algo", 1 - 7100 / 10000),
+])
+def test_grape_readers(metric, want):
+    run = NS(trace_summary=trace.reduce_planes(GRAPE),
+             dataset={"n": 8, "indices": np.zeros(12, np.int32)},
+             cell=NS(chips=2), device_kind="TPU v5 lite")
+    reader, suffix = metric_reader(metric)
+    assert reader.read(run, suffix) == pytest.approx(want)
+
+
+@pytest.mark.parametrize("metric", ["scatter_roofline", "superstep_hbm_share",
+                                    "exchange_share"])
+def test_grape_readers_find_nothing(metric):
+    """A trace with no exchange and no op of the fragment's length: the
+    readers return nothing, not 0."""
+    planes = [GRAPE[0], _plane("/device:TPU:0", {"XLA Ops": [
+        (UPDATE, 0, 500)]})]
+    run = NS(trace_summary=trace.reduce_planes(planes),
+             dataset={"n": 8, "indices": np.zeros(12, np.int32)},
+             cell=NS(chips=2), device_kind="TPU v5 lite")
+    reader, suffix = metric_reader(metric)
+    assert reader.read(run, suffix) is None
