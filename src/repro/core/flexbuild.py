@@ -58,7 +58,7 @@ from repro.storage.grin import (ANALYTICS_REQUIRED, GRINAdapter,
 STORAGE_COMPONENTS = {"vineyard", "gart", "graphar"}
 ENGINE_COMPONENTS = {"gaia", "hiactor", "grape", "graphlearn"}
 INTERFACE_COMPONENTS = {"cypher", "gremlin", "pregel", "pie", "flash",
-                        "sage", "ncn"}
+                        "sage", "ncn", "rgcn"}
 
 ENGINE_TRAITS = {
     "gaia": QUERY_REQUIRED,
@@ -75,6 +75,7 @@ INTERFACE_ENGINE = {
     "flash": {"grape"},
     "sage": {"graphlearn"},
     "ncn": {"graphlearn"},
+    "rgcn": {"graphlearn"},
 }
 
 
@@ -219,8 +220,11 @@ def flexbuild(store=None, components: Optional[Sequence[str]] = None, *,
             engines[name] = HiActorEngine(eng_store)
         elif name == "graphlearn":
             from repro.learning.sampler import GraphSampler
+            # rgcn's sampler draws relations and node types with the
+            # neighbours, over the store's edge and vertex labels
             engines[name] = GraphSampler(eng_store,
                                          feature_prop=feature_prop or "feat",
-                                         label_prop=label_prop)
+                                         label_prop=label_prop,
+                                         typed="rgcn" in comps)
     dep.engines = engines
     return dep

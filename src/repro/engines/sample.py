@@ -28,6 +28,13 @@ unowned entries, so the sum minus one recovers the owner's draw and
 leaves ``PAD_SENTINEL`` (−1) for invalid seeds and isolated vertices —
 the stack-wide padding contract (``storage/partition.py``).
 
+A *typed* executor (``typed=True``, the ``rgcn`` brick) serves relational
+models on a store whose vertex labels are node types and whose edge labels
+are relations, each arc in the row of its target. The relation rides the
+draw: the CSR slab holds ``source · R + relation`` (``relation_codes``),
+so one random read yields both, and a neighbour's node type is its
+relation's source type — only the seeds' types are gathered.
+
 Randomness is a threaded ``jax.random`` key: hop l draws its uniforms from
 ``fold_in(key, l)`` over the FULL frontier (replicated across fragments), so
 results are bit-identical for any F and either exchange — the property the
@@ -50,7 +57,7 @@ from repro.kernels.sampler import (SLAB_VMEM_BYTES, csr_to_sample_ell,
                                    layer_uniforms, sample_csr_jnp,
                                    sample_ell, sample_ell_jnp,
                                    sample_ell_width)
-from repro.storage.grin import GRINAdapter, LEARNING_REQUIRED
+from repro.storage.grin import GRINAdapter, LEARNING_REQUIRED, Traits
 from repro.storage.partition import PAD_SENTINEL
 
 EXCHANGES = ("stacked", "psum")
@@ -101,6 +108,43 @@ def _relayout_rows(table):
     return table
 
 
+def relation_codes(indices: np.ndarray, relations: np.ndarray,
+                   vertex_types: np.ndarray
+                   ) -> Tuple[np.ndarray, np.ndarray, int]:
+    """The typed draw's slab entries: ``(codes, source type of each
+    relation, R)`` with ``codes = indices · R + relations`` (int32), so a
+    draw reads an arc's neighbour and relation in one random access
+    (DESIGN.md §10). Each relation must send from one node type, as a
+    heterogeneous graph's canonical (source type, relation, target type)
+    triples do: that type is the drawn neighbour's, with no gather."""
+    relations = np.asarray(relations, np.int64)
+    vertex_types = np.asarray(vertex_types, np.int64)
+    R = int(relations.max()) + 1 if len(relations) else 1
+    T = int(vertex_types.max()) + 1 if len(vertex_types) else 1
+    if len(vertex_types) * R >= 2 ** 31:
+        raise ValueError(f"{len(vertex_types)} vertices × {R} relations "
+                         "overflow the int32 relation codes")
+    sent = np.zeros(R * T, bool)
+    sent[relations * T + vertex_types[indices]] = True
+    sent = sent.reshape(R, T)
+    mixed = np.flatnonzero(sent.sum(1) > 1)
+    if len(mixed):
+        raise ValueError(f"relations {mixed.tolist()} send from more than "
+                         "one node type")
+    codes = (np.asarray(indices, np.int64) * R + relations).astype(np.int32)
+    return codes, sent.argmax(1).astype(np.int32), R
+
+
+def split_codes(codes: jnp.ndarray, n_relations: int
+                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Typed draws → (neighbour ids, relations), ``PAD_SENTINEL`` in both
+    where the draw is padding."""
+    ok = codes >= 0
+    safe = jnp.where(ok, codes, 0)
+    return (jnp.where(ok, safe // n_relations, PAD_SENTINEL),
+            jnp.where(ok, safe % n_relations, PAD_SENTINEL))
+
+
 class FragmentSampleExecutor:
     """Layered fixed-fanout sampling + feature gather over F fragments."""
 
@@ -109,15 +153,26 @@ class FragmentSampleExecutor:
                  label_prop: Optional[str] = None,
                  use_kernels: bool = False,
                  interpret: Optional[bool] = None, pg=None,
-                 exchange: str = "stacked"):
+                 exchange: str = "stacked", typed: bool = False):
         # ``pg`` shares the query engines' PropertyGraph adjacency caches so
         # learning runs off the same partitioned store as traversal
         if pg is not None:
             store = pg.grin.store
+        if pg is not None and not typed:
             indptr, indices, _ = pg.sliced_csr(None, "out")
         else:
             indptr, indices = store.adjacency()
-        grin = GRINAdapter(store, LEARNING_REQUIRED)
+        grin = GRINAdapter(store, LEARNING_REQUIRED | (
+            Traits.VERTEX_LABEL | Traits.EDGE_LABEL if typed
+            else Traits.NONE))
+        # typed: R relations ride the slab entries; 0 for an untyped
+        # executor, whose program holds no relation or type op
+        self.n_relations = 0
+        vtypes = rel_types = None
+        if typed:
+            vtypes = np.asarray(grin.vertex_labels(), np.int32)
+            indices, rel_types, self.n_relations = relation_codes(
+                indices, grin.edge_labels(), vtypes)
         self.store = store
         self.feature_prop = feature_prop
         self.label_prop = label_prop
@@ -178,6 +233,7 @@ class FragmentSampleExecutor:
             f_deg = np.zeros((F, vp), np.int32)
             f_feat = np.zeros((F, vp, self.feature_dim), np.float32)
             f_lab = None if lab is None else np.zeros((F, vp), np.int32)
+            f_types = None if vtypes is None else np.zeros((F, vp), np.int32)
             for f in range(F):
                 lo, hi = f * vp, min((f + 1) * vp, n)
                 if hi <= lo:                    # fragment past the last row
@@ -187,12 +243,15 @@ class FragmentSampleExecutor:
                 f_feat[f, :hi - lo] = feats[lo:hi]
                 if f_lab is not None:
                     f_lab[f, :hi - lo] = lab[lo:hi]
+                if f_types is not None:
+                    f_types[f, :hi - lo] = vtypes[lo:hi]
             self.ell = jnp.asarray(f_ell)
             self.deg = jnp.asarray(f_deg)
             # under a mesh the table is resharded inside shard_map
             self.feats = (jnp.asarray(f_feat) if mesh is not None
                           else resident_table(f_feat))
             self.labels = None if f_lab is None else jnp.asarray(f_lab)
+            self.types = None if f_types is None else jnp.asarray(f_types)
             self.starts = jnp.arange(F, dtype=jnp.int32) * vp
         else:
             # stacked-reshape fast path: the F fragments ARE rows
@@ -221,6 +280,10 @@ class FragmentSampleExecutor:
                 lab_pad = np.zeros(n + 1, np.int32)
                 lab_pad[:n] = lab
                 self.labels = jnp.asarray(lab_pad)
+            self.types = None
+            if vtypes is not None:
+                self.types = jnp.asarray(np.concatenate([vtypes, [0]]))
+        self.rel_types = None if rel_types is None else jnp.asarray(rel_types)
         self._tables = self._make_tables()
         self._jit_sample = jax.jit(self._sample_impl,
                                    static_argnames=("fanouts",))
@@ -231,11 +294,14 @@ class FragmentSampleExecutor:
         executor with patched same-shape tables reuses the compiled
         program — the sampling analogue of the frontier executor's
         arrays-as-args rule (DESIGN.md §15)."""
-        return {"ell": self.ell, "deg": self.deg, "feats": self.feats,
-                "labels": self.labels,
-                "starts": getattr(self, "starts", None),
-                "csr_starts": getattr(self, "csr_starts", None),
-                "csr_indices": getattr(self, "csr_indices", None)}
+        tables = {"ell": self.ell, "deg": self.deg, "feats": self.feats,
+                  "labels": self.labels,
+                  "starts": getattr(self, "starts", None),
+                  "csr_starts": getattr(self, "csr_starts", None),
+                  "csr_indices": getattr(self, "csr_indices", None)}
+        if self.n_relations:
+            tables.update(types=self.types, rel_types=self.rel_types)
+        return tables
 
     # ------------------------------------------------------- incremental
     def advance(self, store, delta, pg=None
@@ -252,8 +318,11 @@ class FragmentSampleExecutor:
         label tables carry over untouched. Returns ``None`` (callers full-
         rebuild) when the lineage check fails, when the delta touched the
         feature/label property, or when the patched slab would cross a
-        kernel/psum size gate."""
+        kernel/psum size gate, or for a typed executor, whose slab holds
+        relation codes."""
         from repro.storage.csr import topo_base
+        if self.n_relations:
+            return None
         if pg is not None:
             store = pg.grin.store
         indptr1, indices1 = (pg.sliced_csr(None, "out")[:2] if pg is not None
@@ -270,8 +339,9 @@ class FragmentSampleExecutor:
             return None
         new = FragmentSampleExecutor.__new__(FragmentSampleExecutor)
         for f in ("mesh", "exchange", "n_frags", "v_per", "n_vertices",
-                  "use_kernels", "interpret", "feature_dim", "feature_prop",
-                  "label_prop", "feats", "labels", "_jit_sample"):
+                  "n_relations", "use_kernels", "interpret", "feature_dim",
+                  "feature_prop", "label_prop", "feats", "labels",
+                  "_jit_sample"):
             setattr(new, f, getattr(self, f))
         new.store = store
         if old_pos is None or len(new_pos) == 0:
@@ -425,11 +495,17 @@ class FragmentSampleExecutor:
         # device scopes (DESIGN.md §10): a profile names each op by the
         # part of the batch it belongs to, whatever XLA's fusion numbering
         frontiers = [seeds.astype(jnp.int32)]
-        layers = []
+        layers, rels, types = [], [], []
         for l, k in enumerate(fanouts):
             with jax.named_scope(f"sample.hop{l}"):
                 u = layer_uniforms(key, l, frontiers[-1].shape[0], k)
                 nbrs = self._layer(tables, frontiers[-1], u)
+                if self.n_relations:
+                    nbrs, rel = split_codes(nbrs, self.n_relations)
+                    rels.append(rel)
+                    # a neighbour's type is its relation's source type
+                    types.append(jnp.take(tables["rel_types"], jnp.maximum(
+                        rel, 0)).reshape(-1))
             layers.append(nbrs)
             frontiers.append(nbrs.reshape(-1))
         with jax.named_scope("gather.features"):
@@ -438,14 +514,26 @@ class FragmentSampleExecutor:
         if tables["labels"] is not None:
             with jax.named_scope("gather.labels"):
                 labels = self._gather(tables["labels"], frontiers[0])
-        return layers, feats, labels
+        typing = None
+        if self.n_relations:
+            with jax.named_scope("gather.types"):
+                types.insert(0, self._gather(tables["types"], frontiers[0]))
+            typing = (rels, types)
+        return layers, feats, labels, typing
 
     def sample(self, seeds, key, fanouts: Sequence[int]):
-        """One jitted layered batch: seeds [B] → (layers, feats, labels).
+        """One jitted layered batch: seeds [B] → (layers, feats, labels),
+        and for a typed executor (rels, types) after them.
 
         layers[l]: [B·∏f[:l], f[l]] int32 draws (PAD_SENTINEL for invalid);
         feats[l]: frontier-l features [B·∏f[:l], D]; labels [B] int32 (None
-        without a label property). All device-resident jnp arrays."""
+        without a label property); rels[l] the relation of each draw in
+        layers[l] (PAD_SENTINEL for invalid); types[l] the node type of
+        each frontier-l vertex (a padded entry's is meaningless). All
+        device-resident jnp arrays."""
         seeds = jnp.asarray(np.asarray(seeds, np.int32))
-        return self._jit_sample(self._tables, seeds, key,
-                                tuple(int(f) for f in fanouts))
+        out = self._jit_sample(self._tables, seeds, key,
+                               tuple(int(f) for f in fanouts))
+        if self.n_relations:
+            return out[:3] + out[3]
+        return out[:3]

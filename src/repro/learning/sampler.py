@@ -55,7 +55,7 @@ class GraphSampler:
     def __init__(self, store, feature_prop: str = "feat",
                  label_prop: Optional[str] = None, seed: int = 0,
                  backend: str = "host", n_frags: int = 1,
-                 use_kernels: bool = False, pg=None):
+                 use_kernels: bool = False, pg=None, typed: bool = False):
         self.grin = GRINAdapter(store, LEARNING_REQUIRED)
         self.indptr, self.indices = self.grin.adjacency()
         self.feature_prop = feature_prop
@@ -69,6 +69,8 @@ class GraphSampler:
         self.backend = backend
         self.n_frags = n_frags
         self.use_kernels = use_kernels
+        # typed: the device executor draws relations (the rgcn brick)
+        self.typed = typed
         self._pg = pg
         self._seed = seed
         self._device = None
@@ -89,7 +91,7 @@ class GraphSampler:
             self._device = FragmentSampleExecutor(
                 self.grin.store, n_frags=self.n_frags,
                 feature_prop=self.feature_prop, label_prop=self.label_prop,
-                use_kernels=self.use_kernels, pg=self._pg)
+                use_kernels=self.use_kernels, pg=self._pg, typed=self.typed)
         return self._device
 
     def _step_key(self, step: int):
@@ -156,7 +158,7 @@ class GraphSampler:
         the host ``SampledBatch`` layout (the trainer's fully device-resident
         path skips this conversion — see ``SageTrainer`` backend="device")."""
         ex = self.device_executor()
-        layers, feats, labels = ex.sample(seeds, key, tuple(fanouts))
+        layers, feats, labels = ex.sample(seeds, key, tuple(fanouts))[:3]
         return SampledBatch(
             seeds=np.asarray(seeds),
             layers=[np.asarray(l, np.int64) for l in layers],

@@ -175,6 +175,73 @@ class TestServedPathCompiles:
             ("1", "0") if row_major else ("0", "1")]
         assert bool(re.findall(shape + r"\S* copy\(", hlo)) != row_major
 
+    def test_rgcn_train_step_at_ogbn_mag_size(self, one_chip):
+        """The fused R-GCN step (typed draws, per-relation means, the
+        sparse update of the donated input table) over ogbn-mag's 1.94M
+        vertices and 42.2M arcs at its published widths: the table is
+        updated in place, in the row-major layout its gathers read (the
+        chip's default at width 128), without a copy, and the program
+        fits a chip with room to spare."""
+        import re
+
+        from jax.experimental.layout import Layout
+
+        n, e = 1_939_743, 42_222_014
+        device, = one_chip.device_set
+        default = Layout.from_pjrt_layout(device.client.get_default_layout(
+            np.dtype(np.float32), (n + 1, 128), device))
+        assert default.major_to_minor == (0, 1)
+        fn, params, table, tables, step, seeds = _rgcn_step(one_chip, n, e)
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+            params, table, tables, step, seeds).compile()
+        hlo = compiled.as_text()
+        table_param = len(jax.tree_util.tree_leaves(params))
+        assert re.search(r"input_output_alias=\{[^\n]*\(" + str(table_param)
+                         + r", \{\}", hlo)
+        assert not re.findall(re.escape(f"f32[{n + 1},128]") + r"\S* copy\(",
+                              hlo)
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 4e9
+
+
+def _rgcn_step(one_chip, n, e):
+    """The fused R-GCN step of a trainer built over a small typed store,
+    and its argument shapes over ``n`` vertices and ``e`` arcs: hidden
+    64, 349 classes, fanouts 25/20, batch 1024, 7 relations, 4 types."""
+    import copy
+
+    from repro.core.flexbuild import flexbuild
+    from repro.learning.trainer import RGCNTrainer
+    from repro.storage.csr import CSRStore
+
+    # papers, authors, an institution, a field; each relation of ogbn-mag
+    types = np.array([0, 0, 1, 1, 2, 3], np.int32)
+    tgt = np.array([0, 1, 0, 2, 4, 3, 0, 5])
+    src = np.array([1, 0, 2, 0, 2, 4, 5, 0])
+    rel = np.array([0, 0, 1, 2, 3, 4, 6, 5])
+    rng = np.random.default_rng(0)
+    small = CSRStore(6, tgt, src, vertex_props={
+        "feat": rng.standard_normal((6, 128)).astype(np.float32),
+        "label": np.zeros(6, np.int32)}, vertex_labels=types,
+        edge_labels=rel)
+    dep = flexbuild(small, ["vineyard", "graphlearn", "rgcn"],
+                    feature_prop="feat", label_prop="label")
+    tr = RGCNTrainer(dep.engine("graphlearn"), hidden=64, n_classes=349,
+                     fanouts=(25, 20), seed_type=0, embed_types=(1, 2, 3),
+                     batch_size=1024)
+    tr = copy.copy(tr)
+    tr._executor = copy.copy(tr._executor)
+    tr._executor.n_vertices = n
+    S = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    tables = {"ell": None, "starts": None, "feats": None, "deg": S((n,)),
+              "labels": S((n + 1,)), "csr_starts": S((n,)),
+              "csr_indices": S((e + 1,)), "types": S((n + 1,)),
+              "rel_types": S((7,))}
+    return (tr._device_step_fn, _sds(tr.params, one_chip),
+            S((n + 1, 128), jnp.float32), tables, S((), jnp.uint32),
+            S((1024,)))
+
 
 def _sage_step(one_chip, feats_sharding, n, e, fanouts):
     """The fused GraphSAGE step and its argument shapes over ``n`` vertices
