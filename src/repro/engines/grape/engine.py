@@ -4,14 +4,20 @@ Fragment execution follows the paper's design translated to JAX:
 
 - fragments are stacked dense arrays ``[F, ...]`` (partition.py) distributed
   with ``shard_map`` over the ``data`` mesh axis (or ``vmap`` on one device);
-- per superstep each fragment scatters its out-edge contributions into ONE
-  dense length-N message buffer, combined locally (``segment-sum`` combiner)
-  BEFORE a single ``psum``/``pmin``/``pmax`` exchange — the literal analogue
-  of GRAPE's "aggregate fragmented small messages into a continuous compact
-  buffer before dispatching" (the paper trades latency for throughput);
-- the scatter-add hot loop is a jnp scatter on every backend; the Pallas
-  segment-sum behind ``use_kernels`` runs only in tests (the v5e
-  compiler refuses it as written).
+- per superstep each fragment combines its out-edge contributions into ONE
+  dense length-N message buffer, locally, BEFORE a single
+  ``psum``/``pmin``/``pmax`` exchange — the literal analogue of GRAPE's
+  "aggregate fragmented small messages into a continuous compact buffer
+  before dispatching" (the paper trades latency for throughput);
+- a fragment's targets never change, so set-up groups its arcs by target
+  once (a stable device sort, pad arcs last) and each superstep combines
+  the messages with a dense segmented scan over those runs of equal
+  target, read at each vertex's last arc: no sort and no scatter of the
+  arcs per superstep, on every backend. The scan is blocked, rows of 128
+  lanes then the rows' tails, because a 1-D ``lax.associative_scan`` over
+  tens of millions of arcs takes minutes to compile for a TPU. The Pallas
+  segment-sum behind ``use_kernels`` runs only in tests (the v5e compiler
+  refuses it as written).
 """
 
 from __future__ import annotations
@@ -27,36 +33,78 @@ import numpy as np
 from repro.storage.grin import ANALYTICS_REQUIRED, GRINAdapter
 from repro.storage.partition import PAD_SENTINEL, Fragments, partition
 
+# combiner → (operator, identity, cross-chip collective)
 COMBINERS = {
-    "sum": (jnp.zeros, lambda buf, idx, val: buf.at[idx].add(val), "psum"),
-    "min": (lambda shape, dt: jnp.full(shape, jnp.inf, dt),
-            lambda buf, idx, val: buf.at[idx].min(val), "pmin"),
-    "max": (lambda shape, dt: jnp.full(shape, -jnp.inf, dt),
-            lambda buf, idx, val: buf.at[idx].max(val), "pmax"),
+    "sum": (jnp.add, 0.0, "psum"),
+    "min": (jnp.minimum, jnp.inf, "pmin"),
+    "max": (jnp.maximum, -jnp.inf, "pmax"),
 }
+
+LANES = 128   # the blocked scan's row width: one TPU vreg row of lanes
+
+# the per-fragment fields, in the order the superstep passes them
+EDGE_FIELDS = ("indices", "e_src", "e_mask", "weights", "run_last", "run_has")
 
 
 @functools.partial(jax.tree_util.register_dataclass,
                    data_fields=["indices", "e_src", "e_mask", "weights",
-                                "owned_start", "out_degree"],
+                                "run_last", "run_has", "owned_start",
+                                "out_degree"],
                    meta_fields=["n_vertices", "v_per_frag"])
 @dataclasses.dataclass
 class FragmentArrays:
     """Device-resident stacked fragment arrays. A pytree: jitted fixpoints
     take it as an argument, so the edge arrays never bake into the
-    compiled program as constants."""
+    compiled program as constants. Each fragment's arcs are grouped by
+    target (``_group_by_target``): targets ascending, sources ascending
+    within a target's run, pad arcs last."""
 
-    indices: jnp.ndarray        # [F, E] global neighbor ids; PAD_SENTINEL
-    #                             entries are rebased to 0 with e_mask False
-    #                             (scatter-safe: vertex 0 contributions are
-    #                             zeroed by the mask, never by the id)
+    indices: jnp.ndarray        # [F, E] global target ids, non-decreasing;
+    #                             pad arcs (e_mask False) take the last id
     e_src: jnp.ndarray          # [F, E] local owned source index
     e_mask: jnp.ndarray         # [F, E] valid edge
     weights: Optional[jnp.ndarray]
+    run_last: jnp.ndarray       # [F, N] position of each target's last arc
+    #                             (0 where it has none)
+    run_has: jnp.ndarray        # [F, N] the target has an arc here
     owned_start: jnp.ndarray    # [F]
     out_degree: jnp.ndarray     # [N]
     n_vertices: int
     v_per_frag: int
+
+
+def _group_by_target(indices, e_src, mask, weights, n: int):
+    """One fragment's arcs stably sorted by target, pad arcs last; the
+    arcs arrive in (source, target) order, so sources stay ascending
+    within each target's run. Also each target's last arc position and
+    whether it has one."""
+    key = jnp.where(mask, indices, n)            # pads sort after every id
+    ops = (key, e_src) if weights is None else (key, e_src, weights)
+    key, e_src, *w = jax.lax.sort(ops, num_keys=1, is_stable=True)
+    pos = jnp.arange(key.shape[0], dtype=jnp.int32)
+    last = jnp.full((n + 1,), -1, jnp.int32).at[key].max(pos)[:n]
+    return (jnp.minimum(key, n - 1), e_src, key < n,
+            w[0] if w else None, jnp.maximum(last, 0), last >= 0)
+
+
+@functools.lru_cache(maxsize=8)
+def _grouping(mesh, n: int) -> Callable:
+    """``_group_by_target`` over every fragment as one jitted program:
+    under a mesh each chip groups its own fragment in place; on one
+    device a ``vmap`` over the stack. Cached, so the engines rebuilt for
+    snapshots of one vertex count reuse one program; bounded, because
+    each new count is a new program."""
+    group = functools.partial(_group_by_target, n=n)
+    if mesh is None:
+        return jax.jit(jax.vmap(group))
+    from jax.sharding import PartitionSpec as P
+
+    def one(*arrs):
+        out = group(*(None if a is None else a[0] for a in arrs))
+        return tuple(None if a is None else a[None] for a in out)
+
+    return jax.jit(jax.shard_map(one, mesh=mesh, in_specs=P("data"),
+                                 out_specs=P("data")))
 
 
 def _prepare(frags: Fragments, mesh=None) -> FragmentArrays:
@@ -80,16 +128,56 @@ def _prepare(frags: Fragments, mesh=None) -> FragmentArrays:
 
         def replicated(a):
             return jax.device_put(a, NamedSharding(mesh, P()))
+    edges = (np.where(mask, frags.indices, 0).astype(np.int32), e_src,
+             mask, frags.weights)
+    grouped = _grouping(mesh, frags.n_vertices)(
+        *(None if a is None else stacked(a) for a in edges))
     return FragmentArrays(
-        indices=stacked(np.where(mask, frags.indices, 0)),
-        e_src=stacked(e_src),
-        e_mask=stacked(mask),
-        weights=None if frags.weights is None else stacked(frags.weights),
+        **dict(zip(EDGE_FIELDS, grouped)),
         owned_start=stacked(frags.owned_start),
         out_degree=replicated(frags.out_degree),
         n_vertices=frags.n_vertices,
         v_per_frag=frags.v_per_frag,
     )
+
+
+def _segmented_scan(vals, heads, op, identity):
+    """Inclusive scan of ``op`` along the last axis, restarting at each
+    head: log2(width) shift-and-combine steps (Hillis–Steele). Returns
+    the scanned values and whether a head lies at or before each
+    position."""
+    width = vals.shape[-1]
+    lead = [(0, 0)] * (vals.ndim - 1)
+    d = 1
+    while d < width:
+        shift = lead + [(d, 0)]
+        prev_v = jnp.pad(vals[..., :-d], shift, constant_values=identity)
+        prev_h = jnp.pad(heads[..., :-d], shift, constant_values=False)
+        vals = jnp.where(heads, vals, op(prev_v, vals))
+        heads = heads | prev_h
+        d *= 2
+    return vals, heads
+
+
+def _combine_runs(vals, indices, run_last, run_has, op, identity):
+    """Messages ``vals`` [E] in target order → the dense [N] combination
+    of each target's run. Blocked: a segmented scan within rows of
+    ``LANES`` arcs, then across the rows' tails, then one gather at each
+    target's last arc."""
+    E = vals.shape[0]
+    R = -(-E // LANES)
+    heads = jnp.concatenate([jnp.ones((1,), bool),
+                             indices[1:] != indices[:-1]])
+    v = jnp.pad(vals, (0, R * LANES - E), constant_values=identity)
+    h = jnp.pad(heads, (0, R * LANES - E), constant_values=True)
+    v, h = _segmented_scan(v.reshape(R, LANES), h.reshape(R, LANES), op,
+                           identity)
+    # the run open at each row's end, from its first arc, carried into
+    # the next row's arcs before that row's first head
+    tail, _ = _segmented_scan(v[:, -1], h[:, -1], op, identity)
+    carry = jnp.pad(tail[:-1], (1, 0), constant_values=identity)
+    v = jnp.where(h, v, op(carry[:, None], v)).reshape(-1)
+    return jnp.where(run_has, v[run_last], identity)
 
 
 class GrapeEngine:
@@ -112,26 +200,23 @@ class GrapeEngine:
     def _scatter(self, fa: FragmentArrays, owned_vals: jnp.ndarray,
                  combiner: str, use_weights: bool) -> jnp.ndarray:
         """One fragment: owned vertex values → dense length-N contribution."""
-        init, scat, _ = COMBINERS[combiner]
-        vals = owned_vals[fa.e_src]                       # [E]
-        if use_weights and fa.weights is not None:
-            # semiring pairing: (+,×) for sum-combining flows (pagerank,
-            # equity), (min,+) tropical for shortest paths
-            if combiner in ("min", "max"):
-                vals = vals + fa.weights
-            else:
-                vals = vals * fa.weights
-        if combiner == "sum":
-            vals = jnp.where(fa.e_mask, vals, 0.0)
-            if self.use_kernels:
-                from repro.kernels import ops as kops
-                return kops.segment_sum(vals, fa.indices, fa.n_vertices)
-            buf = jnp.zeros((fa.n_vertices,), vals.dtype)
-            return buf.at[fa.indices].add(vals)
-        pad = jnp.inf if combiner == "min" else -jnp.inf
-        vals = jnp.where(fa.e_mask, vals, pad)
-        buf = init((fa.n_vertices,), vals.dtype)
-        return scat(buf, fa.indices, vals)
+        op, identity, _ = COMBINERS[combiner]
+        with jax.named_scope("grape.gather"):
+            vals = owned_vals[fa.e_src]                   # [E]
+            if use_weights and fa.weights is not None:
+                # semiring pairing: (+,×) for sum-combining flows
+                # (pagerank, equity), (min,+) tropical for shortest paths
+                if combiner in ("min", "max"):
+                    vals = vals + fa.weights
+                else:
+                    vals = vals * fa.weights
+            vals = jnp.where(fa.e_mask, vals, identity)
+        if combiner == "sum" and self.use_kernels:
+            from repro.kernels import ops as kops
+            return kops.segment_sum(vals, fa.indices, fa.n_vertices)
+        with jax.named_scope("grape.combine"):
+            return _combine_runs(vals, fa.indices, fa.run_last, fa.run_has,
+                                 op, identity)
 
     def _sharded_superstep(self, combiner: str, use_weights: bool):
         """The jitted shard_map superstep over the mesh's ``data`` axis,
@@ -146,19 +231,20 @@ class GrapeEngine:
         coll = COMBINERS[combiner][2]
         meta = self.frags
 
-        def frag_fn(idx, esrc, emask, w, vals):
-            local_fa = dataclasses.replace(
-                meta, indices=idx[0], e_src=esrc[0], e_mask=emask[0],
-                weights=None if w is None else w[0])
+        def frag_fn(*arrs):
+            *edges, vals = arrs
+            local_fa = dataclasses.replace(meta, **{
+                k: None if a is None else a[0]
+                for k, a in zip(EDGE_FIELDS, edges)})
             contrib = self._scatter(local_fa, vals[0], combiner,
                                     use_weights)
             # the collective's result is identical on every chip
             return getattr(jax.lax, coll)(contrib, "data")
 
-        w_spec = None if meta.weights is None else P("data")
+        specs = tuple(None if getattr(meta, k) is None else P("data")
+                      for k in EDGE_FIELDS)
         fn = jax.jit(jax.shard_map(
-            frag_fn, mesh=self.mesh,
-            in_specs=(P("data"), P("data"), P("data"), w_spec, P("data")),
+            frag_fn, mesh=self.mesh, in_specs=specs + (P("data"),),
             out_specs=P()))
         self._sharded[key] = fn
         return fn
@@ -170,19 +256,19 @@ class GrapeEngine:
         ``frags`` defaults to the engine's arrays; a jitted caller passes
         its traced copy."""
         fa = self.frags if frags is None else frags
+        edges = tuple(getattr(fa, k) for k in EDGE_FIELDS)
 
         if self.mesh is not None:
             fn = self._sharded_superstep(combiner, use_weights)
-            return fn(fa.indices, fa.e_src, fa.e_mask, fa.weights,
-                      owned_vals)
+            return fn(*edges, owned_vals)
 
-        contribs = jax.vmap(
-            lambda i, s, m, w, v: self._scatter(
-                dataclasses.replace(fa, indices=i, e_src=s, e_mask=m,
-                                    weights=w),
-                v, combiner, use_weights),
-            in_axes=(0, 0, 0, None if fa.weights is None else 0, 0),
-        )(fa.indices, fa.e_src, fa.e_mask, fa.weights, owned_vals)
+        def frag_fn(*arrs):
+            *local, vals = arrs
+            return self._scatter(
+                dataclasses.replace(fa, **dict(zip(EDGE_FIELDS, local))),
+                vals, combiner, use_weights)
+
+        contribs = jax.vmap(frag_fn)(*edges, owned_vals)
         if combiner == "sum":
             return jnp.sum(contribs, axis=0)
         if combiner == "min":

@@ -11,6 +11,7 @@ worker that runs this file loads the TPU compiler.
 
 import copy
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +61,32 @@ def _sds(tree, sharding):
 def _compile(fn, *args):
     compiled = jax.jit(fn).lower(*args).compile()
     return compiled.as_text()
+
+
+_HLO_OP = re.compile(r"\s*(?:ROOT )?(%[\w.\-]+) = (.*?) ([a-z][a-z0-9-]*)\((.*)")
+
+
+def _sorts_and_scatters_of(hlo: str, n: int) -> list:
+    """The ``sort`` and ``scatter`` ops of compiled HLO text that give or
+    take an array of ``n`` elements (``[n]``, ``[1,n]`` or ``[n,1]``).
+    The text names an op's operands without their shapes, so each is
+    looked up where it is defined."""
+    shape_of, ops = {}, []
+    for line in hlo.splitlines():
+        m = _HLO_OP.match(line)
+        if m:
+            shape_of[m.group(1)] = m.group(2)
+            ops.append(m)
+    sized = re.compile(rf"\[(1,)?{n}(,1)?\]")
+    found = []
+    for m in ops:
+        if m.group(3) not in ("sort", "scatter"):
+            continue
+        operands = re.findall(r"%[\w.\-]+", m.group(4).split(")")[0])
+        shapes = [m.group(2)] + [shape_of.get(o, "") for o in operands]
+        if any(sized.search(s) for s in shapes):
+            found.append(m.group(0).strip()[:200])
+    return found
 
 
 def _snb_shaped_store():
@@ -121,7 +148,9 @@ class TestServedPathCompiles:
                  _sds(props, one_chip))
 
     def test_grape_pagerank_fixpoint(self, one_chip):
-        """The whole pagerank while-loop over RMAT scale 21 on one chip."""
+        """The whole pagerank while-loop over RMAT scale 21 on one chip,
+        its arcs grouped by target at set-up: no sort and no scatter of
+        the arcs in the superstep, whose two halves carry their scopes."""
         from repro.engines.grape import GrapeEngine, algorithms as alg
         from repro.engines.grape.engine import FragmentArrays
 
@@ -131,17 +160,21 @@ class TestServedPathCompiles:
             eng.use_kernels, eng._sharded = False, {}
             return alg.pagerank(eng)
 
-        e = lambda dt: jax.ShapeDtypeStruct((1, RMAT_E), dt,  # noqa: E731
-                                            sharding=one_chip)
+        def sds(n, dt):
+            return jax.ShapeDtypeStruct((1, n), dt, sharding=one_chip)
+
         frags = FragmentArrays(
-            indices=e(jnp.int32), e_src=e(jnp.int32), e_mask=e(jnp.bool_),
-            weights=None,
+            indices=sds(RMAT_E, jnp.int32), e_src=sds(RMAT_E, jnp.int32),
+            e_mask=sds(RMAT_E, jnp.bool_), weights=None,
+            run_last=sds(RMAT_N, jnp.int32), run_has=sds(RMAT_N, jnp.bool_),
             owned_start=jax.ShapeDtypeStruct((1,), jnp.int32,
                                              sharding=one_chip),
             out_degree=jax.ShapeDtypeStruct((RMAT_N,), jnp.int32,
                                             sharding=one_chip),
             n_vertices=RMAT_N, v_per_frag=RMAT_N)
-        _compile(pagerank_program, frags)
+        hlo = _compile(pagerank_program, frags)
+        assert _sorts_and_scatters_of(hlo, RMAT_E) == []
+        assert "grape.gather" in hlo and "grape.combine" in hlo
 
     def test_fused_sage_train_step(self, one_chip):
         """sample → gather → SGD as one program (DESIGN.md §10) at feature
